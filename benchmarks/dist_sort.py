@@ -83,6 +83,9 @@ def run(json_path: Optional[str] = None) -> None:
 
 
 def main() -> None:
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if os.environ.get(_CHILD_ENV) == "1":
         print("name,us_per_call,derived")
         run(json_path=RESULTS_PATH)

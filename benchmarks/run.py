@@ -44,6 +44,9 @@ def write_bench_json(rows) -> str:
 
 
 def main() -> None:
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--roofline", action="store_true",
                     help="also print the dry-run roofline table")

@@ -191,6 +191,9 @@ def run():
 
 
 def main(check: bool = False) -> int:
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     rows, failures = run()
     if check and failures:
         return 1

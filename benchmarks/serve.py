@@ -476,6 +476,9 @@ def run():
 
 def main(check: bool = False, faults: bool = False,
          trace_requests: bool = False) -> int:
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     failures = []
     if trace_requests:
         # standalone mode: only the request-trace gate runs (CI's schema

@@ -321,14 +321,13 @@ def measured_dispatch_enabled() -> bool:
 
 
 def _route_key(spec: SortSpec, backend: str) -> str:
-    """Cache key for one (op, shapes, dtype, k, payload, platform, backend)
-    route sample. The platform rides in the key's backend tag so TPU and
-    CPU timings never rank against each other."""
-    import jax
+    """Cache key for one (op, shapes, dtype, k, payload, device, backend)
+    route sample. The device kind rides in the key's backend tag so TPU and
+    CPU timings — or two TPU generations — never rank against each
+    other."""
+    from repro.streaming.cache import device_tag, plan_key
 
-    from repro.streaming.cache import plan_key
-
-    tag = (f"{jax.default_backend()}:"
+    tag = (f"{device_tag()}:"
            f"{'payload' if spec.has_payload else 'plain'}:{backend}")
     return plan_key(f"route_{spec.op}",
                     shapes=(spec.batch,) + tuple(spec.lengths),

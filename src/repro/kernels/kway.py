@@ -1,20 +1,17 @@
 """Pallas TPU kernel: generic schedule-driven k-way LOMS merge.
 
-Runs any :class:`repro.core.networks.Schedule` inside a Pallas kernel. The
-schedule's wiring (setup scatter, per-stage group indices, output gather)
-is passed as int32 operand arrays — Pallas does not allow captured
-constants — and every stage unrolls at trace time into:
-  wiring take -> comparison cloud (VPU) -> one-hot permute (MXU) -> wiring
-  scatter.
-This is the general path (3c_7r, mixed list sizes, medians); the 2-way
-fast path (pure strided reshapes, no index operands) lives in
-loms_merge.py.
-
-Wiring residency: the wiring operands are grid-constant, so when their
-total size fits the scalar-memory budget they ride a
-``PrefetchScalarGridSpec`` — fetched once into SMEM before the first grid
-step instead of being re-blocked by the pipeline on every step. Past the
-budget (huge schedules) the legacy per-step ``BlockSpec`` path is kept.
+Runs any :class:`repro.core.networks.Schedule` inside a Pallas kernel.
+The schedule's wiring is static, but Pallas does not allow captured
+constants, so it rides as one small int32 operand (a whole-array block,
+fetched once): per stage, every working cell's group id, run, position
+inside its run and slot inside its group. Each stage is then lane-flat
+on the working vector:
+  ranks of every cell inside its group (comparison cloud, VPU) -> slot
+  lookup -> one one-hot permute.
+The setup scatter and the output gather are one-hot permutes too, so no
+gather, scatter or SMEM vector load reaches the TPU lowering. This is the
+general path (3c_7r, mixed list sizes, medians); the 2-way fast path
+lives in loms_merge.py.
 
 Fused pipeline extensions (DESIGN.md §11): ``key_dtype`` applies the
 total-order float->int key transform on load/store inside the kernel,
@@ -26,150 +23,141 @@ merge is still one launch.
 from __future__ import annotations
 
 import functools
-from typing import List, Optional, Sequence, Tuple
+from typing import Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.networks import Schedule, _stage_classes
+from repro.core.networks import Schedule
 
 from .common import (
     _iota,
-    decode_key_values,
-    encode_key_values,
+    decode_kernel_keys,
+    encode_kernel_keys,
+    for_row_slabs,
     gather_lanes,
-    onehot_permute,
+    narrow_back,
     pad_batch,
-    payload_block_spec,
-    ranks_sort,
+    pair_sum,
+    permute_lanes,
     resolve_interpret,
-    scatter_permute,
+    reverse_lanes,
+    row_block_spec,
+    row_slab,
+    slab_lanes,
+    run_rank,
+    split_payload_lanes,
+    tile_rows,
     unpack_fused_results,
 )
 
-#: largest total wiring size (int32 elements) routed through scalar
-#: prefetch; SMEM is tens of KiB per core, so bigger schedules keep the
-#: legacy VMEM-operand path.
-KWAY_PREFETCH_MAX_INTS = 4096
+#: wiring rows per stage: group id, run, position in run, slot in group
+_STAGE_ROWS = 4
 
 
-def _schedule_wiring(sched: Schedule, n_stages=None) -> List[np.ndarray]:
-    """Collect every constant index array the kernel needs, in read order."""
-    wiring = [np.asarray(sched.setup_scatter, dtype=np.int32)]
+def _schedule_wiring(sched: Schedule, n_stages=None) -> np.ndarray:
+    """The schedule's static wiring as one int32 matrix: row 0 the setup
+    scatter, row 1 the output gather, then ``_STAGE_ROWS`` rows per stage
+    over the working vector. A cell outside every group of a stage is its
+    own group (unique negative id), so the stage leaves it in place."""
+    size = sched.size
     stages = sched.stages if n_stages is None else sched.stages[:n_stages]
+    width = max(size, sched.n_inputs, sched.n_outputs)
+    rows = [np.asarray(sched.setup_scatter, np.int64),
+            np.asarray(sched.output_gather, np.int64)]
     for st in stages:
-        for _, _, idx in _stage_classes(st):
-            wiring.append(idx.reshape(-1).astype(np.int32))
-    wiring.append(np.asarray(sched.output_gather, dtype=np.int32))
-    return wiring
+        grp = -1 - np.arange(size)
+        run = np.zeros(size, np.int64)
+        local = np.zeros(size, np.int64)
+        slot = np.zeros(size, np.int64)
+        for gi, g in enumerate(st.groups):
+            if g.n <= 1:
+                continue
+            runs = g.runs if g.runs is not None else (1,) * g.n
+            offs = np.cumsum((0,) + tuple(runs))
+            for o, cell in enumerate(g.idx):
+                r = int(np.searchsorted(offs, o, side="right") - 1)
+                grp[cell], run[cell] = gi, r
+                local[cell], slot[cell] = o - offs[r], o
+        rows += [grp, run, local, slot]
+    out = np.full((len(rows), width), -1, np.int32)
+    for i, r in enumerate(rows):
+        out[i, :len(r)] = r
+    return out
+
+
+def _segment_reversal(lens: Tuple[int, ...]) -> jnp.ndarray:
+    """(1, total) destinations reversing every list segment in place."""
+    total = sum(lens)
+    lane = _iota((1, total), 1)
+    dest = lane
+    off = 0
+    for ln in lens:
+        inside = (lane >= off) & (lane < off + ln)
+        dest = jnp.where(inside, 2 * off + ln - 1 - lane, dest)
+        off += ln
+    return dest
 
 
 def _kway_kernel(
+    x_ref,
+    w_ref,
     *refs,
     sched: Schedule,
     n_stages,
     use_mxu: bool,
-    n_wiring: int,
-    prefetch: bool,
     lens: Optional[Tuple[int, ...]],
     key_dtype: Optional[str],
     descending: bool,
     n_payload: int,
     want_perm: bool,
 ):
-    # argument order: prefetch mode puts the scalar wiring refs first,
-    # the legacy mode keeps them between x and the payload lanes
-    if prefetch:
-        wiring = [r[...] for r in refs[:n_wiring]]
-        x_ref = refs[n_wiring]
-        rest = refs[n_wiring + 1:]
-    else:
-        x_ref = refs[0]
-        wiring = [r[...] for r in refs[1 : 1 + n_wiring]]
-        rest = refs[1 + n_wiring:]
-    p_refs = rest[:n_payload]
-    o_ref = rest[n_payload]
-    perm_ref = rest[n_payload + 1] if want_perm else None
-    po_refs = rest[n_payload + 1 + (1 if want_perm else 0):]
-
-    x = x_ref[...]
-    bt = x.shape[0]
-    n_in = x.shape[-1]
+    p_refs = refs[:n_payload]
+    out_refs = refs[n_payload:]
+    n_in, n_out, size = sched.n_inputs, sched.n_outputs, sched.size
+    n_st = len(sched.stages if n_stages is None else sched.stages[:n_stages])
     need_pos = n_payload > 0 or want_perm
-    pos = _iota((bt, n_in), 1) if need_pos else None
-    if descending:
-        # reverse each list segment in-register -> ascending problem whose
-        # position lane still indexes the original (descending) concat
-        assert lens is not None
-        offs = np.cumsum((0,) + tuple(lens))
-        x = jnp.concatenate(
-            [x[:, offs[j] : offs[j + 1]][:, ::-1] for j in range(len(lens))],
-            axis=-1,
-        )
-        if need_pos:
-            pos = jnp.concatenate(
-                [pos[:, offs[j] : offs[j + 1]][:, ::-1] for j in range(len(lens))],
-                axis=-1,
-            )
-    if key_dtype is not None:  # fused nan_policy="last" encode on load
-        x = encode_key_values(x)
-    stages = sched.stages if n_stages is None else sched.stages[:n_stages]
-    permute = onehot_permute if use_mxu else scatter_permute
 
-    wi = iter(wiring)
-    setup = next(wi)
-    w = jnp.zeros((bt, sched.size), dtype=x.dtype)
-    w = w.at[:, setup].set(x)
-    wp = None
-    if need_pos:
-        wp = jnp.zeros((bt, sched.size), dtype=jnp.int32)
-        wp = wp.at[:, setup].set(pos)
-    for st in stages:
-        for n, runs, idx in _stage_classes(st):
-            flat = next(wi)
-            vals = jnp.take(w, flat, axis=-1).reshape(bt, *idx.shape)
-            if runs is None:
-                rank = ranks_sort(vals)
-            else:
-                offs = np.cumsum((0,) + runs)
-                pieces = [vals[..., offs[s] : offs[s + 1]] for s in range(len(runs))]
-                rr = []
-                for s, vs in enumerate(pieces):
-                    r = _iota((1, 1, runs[s]), 2)[0]
-                    r = jnp.broadcast_to(r, vs.shape).astype(jnp.int32)
-                    for t, vt in enumerate(pieces):
-                        if t == s:
-                            continue
-                        if t < s:
-                            cnt = (vt[..., None, :] <= vs[..., :, None]).sum(-1)
-                        else:
-                            cnt = (vt[..., None, :] < vs[..., :, None]).sum(-1)
-                        r = r + cnt.astype(jnp.int32)
-                    rr.append(r)
-                rank = jnp.concatenate(rr, axis=-1)
-            if need_pos:
-                pvals = jnp.take(wp, flat, axis=-1).reshape(bt, *idx.shape)
-                vals, pvals = permute(vals, rank, pvals)
-                wp = wp.at[:, flat].set(pvals.reshape(bt, len(idx.reshape(-1))))
-            else:
-                vals = permute(vals, rank)
-            w = w.at[:, flat].set(vals.reshape(bt, len(idx.reshape(-1))))
-    gather = next(wi)
-    out = jnp.take(w, gather, axis=-1)
-    perm = jnp.take(wp, gather, axis=-1).astype(jnp.int32) if need_pos else None
-    if key_dtype is not None:  # fused decode on store
-        out = decode_key_values(out, key_dtype)
-    if descending:
-        out = out[:, ::-1]
-        perm = None if perm is None else perm[:, ::-1]
-    o_ref[...] = out
-    if want_perm:
-        perm_ref[...] = perm
-    for p_ref, po_ref in zip(p_refs, po_refs):
-        po_ref[...] = gather_lanes(perm, p_ref[...])
+    def row(r, width):
+        return w_ref[r:r + 1, :width]
+
+    def body(x, *leaves):
+        bt = x.shape[0]
+        arrays = [x] + ([_iota((bt, n_in), 1)] if need_pos else [])
+        if descending:
+            # reverse each list segment -> ascending problem whose position
+            # lane still indexes the original (descending) concat
+            arrays = permute_lanes(_segment_reversal(lens), arrays, n_in,
+                                   use_mxu=use_mxu)
+        if key_dtype is not None:  # fused nan_policy="last" encode on load
+            arrays[0] = encode_kernel_keys(arrays[0])
+        arrays = permute_lanes(row(0, n_in), arrays, size, use_mxu=use_mxu)
+        for s in range(n_st):
+            grp, run, local, slot = (row(2 + _STAGE_ROWS * s + j, size)
+                                     for j in range(_STAGE_ROWS))
+            rank = run_rank(arrays[0], grp, run, local)
+            # the cell holding slot ``rank`` of the same group
+            dest = pair_sum(
+                lambda qs, ps, qi, pi: jnp.where(
+                    (qs[0] == ps[0]) & (qs[1] == ps[1]), qi, 0),
+                [grp, slot], [grp, rank], size)
+            arrays = permute_lanes(dest, arrays, size, use_mxu=use_mxu)
+        gather = row(1, n_out)
+        out = gather_lanes(gather, arrays[0])
+        perm = gather_lanes(gather, arrays[1]) if need_pos else None
+        if key_dtype is not None:  # fused decode on store
+            out = decode_kernel_keys(out, key_dtype)
+        if descending:
+            out, *rest = reverse_lanes([out] + ([perm] if need_pos else []),
+                                       use_mxu=use_mxu)
+            perm = rest[0] if need_pos else None
+        outs = [out] + ([perm] if want_perm else [])
+        return outs + [gather_lanes(perm, leaf) for leaf in leaves]
+
+    for_row_slabs([x_ref, *p_refs], out_refs, body,
+                  row_slab(x_ref.shape[0], size))
 
 
 def kway_merge_pallas(
@@ -210,49 +198,44 @@ def kway_merge_pallas(
             p.shape, (bsz, n_in))
     if descending:
         assert lens is not None and sum(lens) == n_in, (lens, n_in)
+    lanes, rebuild = split_payload_lanes(payloads)
+    block_batch = tile_rows(block_batch, bsz)
     x = pad_batch(x, block_batch)
-    payloads_p = tuple(pad_batch(p, block_batch) for p in payloads)
+    lanes = [pad_batch(p, block_batch) for p in lanes]
+    dtypes = [x.dtype] + [p.dtype for p in lanes]
+    x, *lanes = slab_lanes([x, *lanes], block_batch, sched.size)
     padded = x.shape[0]
     wiring = _schedule_wiring(sched, n_stages)
-    prefetch = sum(w.size for w in wiring) <= KWAY_PREFETCH_MAX_INTS
+    n_out = sched.n_outputs
     kernel = functools.partial(
         _kway_kernel, sched=sched, n_stages=n_stages, use_mxu=use_mxu,
-        n_wiring=len(wiring), prefetch=prefetch, lens=lens,
-        key_dtype=key_dtype, descending=descending, n_payload=len(payloads),
-        want_perm=want_perm,
+        lens=lens, key_dtype=key_dtype, descending=descending,
+        n_payload=len(lanes), want_perm=want_perm,
     )
-    out_specs = [pl.BlockSpec((block_batch, sched.n_outputs),
-                              lambda i, *_: (i, 0))]
-    out_shapes = [jax.ShapeDtypeStruct((padded, sched.n_outputs), x.dtype)]
+    out_specs = [row_block_spec(block_batch, n_out)]
+    out_shapes = [jax.ShapeDtypeStruct((padded, n_out), x.dtype)]
     if want_perm:
-        out_specs.append(pl.BlockSpec((block_batch, sched.n_outputs),
-                                      lambda i, *_: (i, 0)))
-        out_shapes.append(jax.ShapeDtypeStruct((padded, sched.n_outputs),
-                                               jnp.int32))
-    out_specs += [payload_block_spec(p, block_batch) for p in payloads_p]
-    out_shapes += [jax.ShapeDtypeStruct(p.shape, p.dtype) for p in payloads_p]
-    x_spec = pl.BlockSpec((block_batch, n_in), lambda i, *_: (i, 0))
-    p_specs = [payload_block_spec(p, block_batch) for p in payloads_p]
-    grid = (padded // block_batch,)
-    if prefetch:
-        # grid-constant wiring rides scalar prefetch: fetched once, not
-        # re-blocked every grid step
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=len(wiring),
-            grid=grid,
-            in_specs=[x_spec, *p_specs],
-            out_specs=out_specs,
-        )
-        results = pl.pallas_call(
-            kernel, grid_spec=grid_spec, out_shape=out_shapes,
-            interpret=interpret,
-        )(*[jnp.asarray(w) for w in wiring], x, *payloads_p)
-    else:
-        in_specs = [x_spec]
-        in_specs += [pl.BlockSpec(w.shape, lambda i: (0,)) for w in wiring]
-        in_specs += p_specs
-        results = pl.pallas_call(
-            kernel, grid=grid, in_specs=in_specs, out_specs=out_specs,
-            out_shape=out_shapes, interpret=interpret,
-        )(x, *[jnp.asarray(w) for w in wiring], *payloads_p)
-    return unpack_fused_results(results, bsz, padded, len(payloads), want_perm)
+        out_specs.append(row_block_spec(block_batch, n_out))
+        out_shapes.append(jax.ShapeDtypeStruct((padded, n_out), jnp.int32))
+    out_specs += [row_block_spec(block_batch, n_out) for _ in lanes]
+    out_shapes += [jax.ShapeDtypeStruct((padded, n_out), p.dtype)
+                   for p in lanes]
+    results = pl.pallas_call(
+        kernel,
+        grid=(padded // block_batch,),
+        in_specs=[
+            row_block_spec(block_batch, n_in),
+            # grid-constant wiring: a whole-array block, fetched once
+            pl.BlockSpec(wiring.shape, lambda i: (0, 0)),
+            *[row_block_spec(block_batch, n_in) for _ in lanes],
+        ],
+        out_specs=out_specs,
+        out_shape=out_shapes,
+        interpret=interpret,
+    )(x, jnp.asarray(wiring), *lanes)
+    results = narrow_back(results, dtypes, want_perm)
+    res = unpack_fused_results(results, bsz, padded, len(lanes), want_perm)
+    if not payloads and not want_perm:
+        return res
+    out, perm, pouts = res
+    return out, perm, rebuild(pouts)

@@ -4,11 +4,12 @@ Layout strategy (hardware adaptation, DESIGN.md §2): the k-column setup
 array for UP-m/DN-n with C columns assigns
     A_j  -> column j % C            (ascending stride-C slices of ``a``)
     B_j  -> column (n-1-j) % C      (ascending stride-C slices of ``b``)
-so for C | m and C | n the whole setup array is built from *strided
-reshapes* — no gathers touch VMEM. Stage 1 merges each column's two runs
-with the S2MS comparison cloud (VPU) + one-hot permute (MXU); stage 2
-rank-sorts each row of C values. Output is the row-major flatten, again a
-plain reshape.
+The kernel never builds that array: column, row position and run are
+lane arithmetic on ``concat(a, b)``, stage 1 ranks every element inside
+its column (the S2MS comparison cloud on the VPU), stage 2 ranks it
+inside its row, and one one-hot permute writes the row-major flatten.
+Only static slices, compares, selects and sublane sums reach the TPU
+lowering (``repro.kernels.common`` lane primitives).
 
 Fused pipeline extensions (DESIGN.md §11): the kernel optionally
 * encodes the total-order float->int key transform on load and decodes it
@@ -17,16 +18,16 @@ Fused pipeline extensions (DESIGN.md §11): the kernel optionally
 * threads an int32 position lane through the same permutes and gathers
   payload lanes in VMEM (``payloads=``), so payload merges stop
   materializing an index array and gathering at the XLA level,
-* handles ``descending=`` inputs by reversing on load/store in-register.
+* handles ``descending=`` inputs by a lane-reversal permute on load/store.
 
-Per-block VMEM: (m+n) values + the widest column comparison matrix
-(m/C * n/C bools) + the row-sort matrix (R * C^2) — tile the batch so this
-fits the ~16 MiB VMEM budget (``streaming.planner`` picks the tile).
+Per-block VMEM: the (B, m+n) lanes plus one (rows, m+n, 128) pairwise
+tile; the kernel walks row slabs (``row_slab``) so the tile stays small
+whatever ``block_batch`` the planner picks.
 """
 from __future__ import annotations
 
 import functools
-from typing import Optional, Sequence, Tuple
+from typing import Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -36,12 +37,19 @@ from repro.networks import merge_program, merge_runs
 
 from .common import (
     _iota,
-    decode_key_values,
-    encode_key_values,
+    decode_kernel_keys,
+    encode_kernel_keys,
+    for_row_slabs,
     gather_lanes,
+    narrow_back,
     pad_batch,
-    payload_block_spec,
     resolve_interpret,
+    reverse_lanes,
+    row_block_spec,
+    row_slab,
+    slab_lanes,
+    split_payload_lanes,
+    tile_rows,
     unpack_fused_results,
 )
 
@@ -59,50 +67,42 @@ def _loms2_kernel(
     want_perm: bool,
 ):
     p_refs = refs[:n_payload]
-    o_ref = refs[n_payload]
-    perm_ref = refs[n_payload + 1] if want_perm else None
-    po_refs = refs[n_payload + 1 + (1 if want_perm else 0):]
-
-    a = a_ref[...]  # (bt, m) ascending (descending reversed below)
-    b = b_ref[...]  # (bt, n)
-    bt, m = a.shape
-    n = b.shape[-1]
-    if descending:  # reverse in-register: the merge itself is ascending
-        a, b = a[:, ::-1], b[:, ::-1]
-    if key_dtype is not None:  # fused nan_policy="last" encode
-        a = encode_key_values(a)
-        b = encode_key_values(b)
+    out_refs = refs[n_payload:]
+    m, n = a_ref.shape[-1], b_ref.shape[-1]
+    # the merge structure comes from the network registry: the LOMS column
+    # device, the S2MS cloud, or a pair network
+    prog = merge_program(network, m, n,
+                         n_cols if network == "loms" else None)
     need_pos = n_payload > 0 or want_perm
-    pa = pb = None
-    if need_pos:
+
+    def body(a, b, *leaves):
+        bt = a.shape[0]
         # positions index the *original* orientation of concat(a, b), the
         # same convention the unfused executor's position payload uses
         pa = _iota((bt, m), 1)
         pb = _iota((bt, n), 1) + m
+        if descending:  # reverse on load: the merge itself is ascending
+            a, pa = reverse_lanes([a, pa], use_mxu=use_mxu)
+            b, pb = reverse_lanes([b, pb], use_mxu=use_mxu)
+        if key_dtype is not None:  # fused nan_policy="last" encode
+            a = encode_kernel_keys(a)
+            b = encode_kernel_keys(b)
+        if need_pos:
+            out, perm = merge_runs(prog, a, b, use_mxu=use_mxu,
+                                   payload=(pa, pb))
+        else:
+            out, perm = merge_runs(prog, a, b, use_mxu=use_mxu), None
+        if key_dtype is not None:  # fused decode on store
+            out = decode_kernel_keys(out, key_dtype)
         if descending:
-            pa = (m - 1) - _iota((bt, m), 1)
-            pb = ((n - 1) - _iota((bt, n), 1)) + m
-    # the merge structure comes from the network registry: the LOMS column
-    # device (n_cols strided views), the S2MS cloud, or a pair network
-    prog = merge_program(network, m, n,
-                         n_cols if network == "loms" else None)
-    if need_pos:
-        out, perm = merge_runs(prog, a, b, use_mxu=use_mxu,
-                               payload=(pa, pb))
-        perm = perm.astype(jnp.int32)
-    else:
-        out = merge_runs(prog, a, b, use_mxu=use_mxu)
-        perm = None
-    if key_dtype is not None:  # fused decode on store
-        out = decode_key_values(out, key_dtype)
-    if descending:
-        out = out[:, ::-1]
-        perm = None if perm is None else perm[:, ::-1]
-    o_ref[...] = out
-    if want_perm:
-        perm_ref[...] = perm
-    for p_ref, po_ref in zip(p_refs, po_refs):
-        po_ref[...] = gather_lanes(perm, p_ref[...])
+            out, *rest = reverse_lanes([out] + ([perm] if need_pos else []),
+                                       use_mxu=use_mxu)
+            perm = rest[0] if need_pos else None
+        outs = [out] + ([perm] if want_perm else [])
+        return outs + [gather_lanes(perm, leaf) for leaf in leaves]
+
+    for_row_slabs([a_ref, b_ref, *p_refs], out_refs, body,
+                  row_slab(a_ref.shape[0], m + n))
 
 
 @functools.partial(
@@ -156,32 +156,40 @@ def loms_merge2_pallas(
     for p in payloads:
         assert p.ndim in (2, 3) and p.shape[:2] == (bsz, m + n), (
             p.shape, (bsz, m + n))
+    lanes, rebuild = split_payload_lanes(payloads)
+    block_batch = tile_rows(block_batch, bsz)
     a, b = pad_batch(a, block_batch), pad_batch(b, block_batch)
-    payloads = tuple(pad_batch(p, block_batch) for p in payloads)
+    lanes = [pad_batch(p, block_batch) for p in lanes]
+    dtypes = [a.dtype] + [p.dtype for p in lanes]
+    a, b, *lanes = slab_lanes([a, b, *lanes], block_batch, m + n)
     padded = a.shape[0]
     grid = (padded // block_batch,)
-    p_specs = [payload_block_spec(p, block_batch) for p in payloads]
-    out_specs = [pl.BlockSpec((block_batch, m + n), lambda i: (i, 0))]
+    out_specs = [row_block_spec(block_batch, m + n)]
     out_shapes = [jax.ShapeDtypeStruct((padded, m + n), a.dtype)]
     if want_perm:
-        out_specs.append(pl.BlockSpec((block_batch, m + n), lambda i: (i, 0)))
+        out_specs.append(row_block_spec(block_batch, m + n))
         out_shapes.append(jax.ShapeDtypeStruct((padded, m + n), jnp.int32))
-    out_specs += [payload_block_spec(p, block_batch) for p in payloads]
-    out_shapes += [jax.ShapeDtypeStruct(p.shape, p.dtype) for p in payloads]
+    out_specs += [row_block_spec(block_batch, m + n) for _ in lanes]
+    out_shapes += [jax.ShapeDtypeStruct(p.shape, p.dtype) for p in lanes]
     results = pl.pallas_call(
         functools.partial(
             _loms2_kernel, network=network, n_cols=n_cols, use_mxu=use_mxu,
             key_dtype=key_dtype, descending=descending,
-            n_payload=len(payloads), want_perm=want_perm,
+            n_payload=len(lanes), want_perm=want_perm,
         ),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((block_batch, m), lambda i: (i, 0)),
-            pl.BlockSpec((block_batch, n), lambda i: (i, 0)),
-            *p_specs,
+            row_block_spec(block_batch, m),
+            row_block_spec(block_batch, n),
+            *[row_block_spec(block_batch, m + n) for _ in lanes],
         ],
         out_specs=out_specs,
         out_shape=out_shapes,
         interpret=interpret,
-    )(a, b, *payloads)
-    return unpack_fused_results(results, bsz, padded, len(payloads), want_perm)
+    )(a, b, *lanes)
+    results = narrow_back(results, dtypes, want_perm)
+    res = unpack_fused_results(results, bsz, padded, len(lanes), want_perm)
+    if not payloads and not want_perm:
+        return res
+    out, perm, pouts = res
+    return out, perm, rebuild(pouts)

@@ -9,7 +9,7 @@ unrolled LOMS network once for the whole class:
   load -> (encode total-order int keys) -> (bit-flip for descending) ->
   overwrite the invalid tail lanes with the key-domain +sentinel ->
   unrolled LOMS merge tree (sort) or column S2MS merge (merge) carrying an
-  int32 position lane -> mask-compact validity (``stable_compact`` — a pad
+  int32 position lane -> mask-compact validity (``compact_lanes`` — a pad
   can never displace a real element, even when a genuine NaN key sits
   above the float sentinel) -> gather the *raw* input values and payload
   lanes at the permutation in VMEM -> store the (optionally truncated)
@@ -40,13 +40,20 @@ from repro.networks import (merge_program, merge_runs, pick_merge_cols,
 
 from .common import (
     _iota,
-    encode_key_values,
+    compact_lanes,
+    encode_kernel_keys,
+    for_row_slabs,
     gather_lanes,
+    narrow_back,
     pad_batch,
-    payload_block_spec,
     resolve_interpret,
-    stable_compact,
+    row_block_spec,
+    row_slab,
+    slab_lanes,
+    split_payload_lanes,
+    tile_rows,
     unpack_fused_results,
+    widen,
 )
 
 
@@ -71,7 +78,7 @@ def flip_keys(k: jnp.ndarray) -> jnp.ndarray:
 
 def _prep_keys(x, lens, *, encode: bool, flip: bool):
     """values -> masked network keys + the validity of each input lane."""
-    keys = encode_key_values(x) if encode else x
+    keys = encode_kernel_keys(x) if encode else widen(x)
     if flip:
         keys = flip_keys(keys)
     lane = _iota(x.shape, 1)
@@ -79,37 +86,36 @@ def _prep_keys(x, lens, *, encode: bool, flip: bool):
     return jnp.where(valid_in, keys, key_sentinel(keys.dtype)), lane
 
 
-def _store_prefix(refs, pos, x_vals, p_ins, k_out: int, want_perm: bool,
-                  seg_pos=None):
-    """Shared epilogue: gather raw values + payload lanes at the compacted
-    permutation and store the ``k_out`` prefix."""
-    n_payload = len(p_ins)
-    o_ref = refs[0]
-    perm_ref = refs[1] if want_perm else None
-    po_refs = refs[1 + (1 if want_perm else 0):]
-    o_ref[...] = gather_lanes(pos, x_vals)[:, :k_out]
-    if want_perm:
-        perm_ref[...] = (pos if seg_pos is None else seg_pos)[:, :k_out]
-    for p_in, po_ref in zip(p_ins, po_refs):
-        po_ref[...] = gather_lanes(pos, p_in)[:, :k_out]
-
-
 def _seg_sort_kernel(
     x_ref, len_ref, *refs,
     w: int, k_out: int, network: str, encode: bool, flip: bool,
     use_mxu: bool, n_payload: int, want_perm: bool,
 ):
-    p_ins = tuple(r[...] for r in refs[:n_payload])
-    x = x_ref[...]  # (bt, w) raw, invalid tail lanes hold arbitrary fill
-    lens = len_ref[...]  # (bt, 1) per-segment valid lengths
-    keys, lane = _prep_keys(x, lens, encode=encode, flip=flip)
-    keys, pos = run_sort_program(sort_program(network, w), keys, lane,
-                                 use_mxu)
-    # validity by mask, never by value: a genuine NaN key sorts above the
-    # float sentinel, so the compacted prefix — not the raw network order —
-    # defines the live output
-    keys, pos = stable_compact(pos < lens, keys, pos)
-    _store_prefix(refs[n_payload:], pos, x, p_ins, k_out, want_perm)
+    def body(x, lens, *p_ins):
+        # x: (bt, w) raw, invalid tail lanes hold arbitrary fill; lens:
+        # (bt, 1) per-segment valid lengths
+        keys, lane = _prep_keys(x, lens, encode=encode, flip=flip)
+        keys, pos = run_sort_program(sort_program(network, w), keys, lane,
+                                     use_mxu)
+        # validity by mask, never by value: a genuine NaN key sorts above
+        # the float sentinel, so the compacted prefix — not the raw
+        # network order — defines the live output
+        pos = compact_lanes(pos < lens, [pos], use_mxu=use_mxu)[0]
+        return _prefix_outs(pos, x, p_ins, k_out, want_perm)
+
+    for_row_slabs([x_ref, len_ref, *refs[:n_payload]], refs[n_payload:],
+                  body, row_slab(x_ref.shape[0], w))
+
+
+def _prefix_outs(pos, x_vals, p_ins, k_out: int, want_perm: bool,
+                 seg_pos=None):
+    """Shared epilogue: the raw values and payload lanes gathered at the
+    compacted permutation, truncated to the ``k_out`` prefix."""
+    pos = pos[:, :k_out]
+    outs = [gather_lanes(pos, x_vals)]
+    if want_perm:
+        outs.append(pos if seg_pos is None else seg_pos[:, :k_out])
+    return outs + [gather_lanes(pos, p) for p in p_ins]
 
 
 def _seg_merge_kernel(
@@ -117,49 +123,56 @@ def _seg_merge_kernel(
     wa: int, wb: int, k_out: int, network: str, n_cols: int, encode: bool,
     flip: bool, use_mxu: bool, n_payload: int, want_perm: bool,
 ):
-    p_ins = tuple(r[...] for r in refs[:n_payload])
-    a = a_ref[...]
-    b = b_ref[...]
-    lens_a = la_ref[...]
-    lens_b = lb_ref[...]
-    ka, lane_a = _prep_keys(a, lens_a, encode=encode, flip=flip)
-    kb, lane_b = _prep_keys(b, lens_b, encode=encode, flip=flip)
     # dense-coordinate positions: [0, wa) = a lanes, [wa, wa+wb) = b lanes
     prog = merge_program(network, wa, wb,
                          n_cols if network == "loms" else None)
-    keys, pos = merge_runs(prog, ka, kb,
-                           payload=(lane_a, wa + lane_b), use_mxu=use_mxu)
-    valid = jnp.where(pos < wa, pos < lens_a, pos - wa < lens_b)
-    keys, pos = stable_compact(valid, keys, pos)
-    # perm in *segment* coordinates: b elements continue at len_a, not wa
-    seg_pos = jnp.where(pos < wa, pos, lens_a + (pos - wa))
-    _store_prefix(refs[n_payload:], pos, jnp.concatenate([a, b], axis=1),
-                  p_ins, k_out, want_perm, seg_pos=seg_pos)
+
+    def body(a, b, lens_a, lens_b, *p_ins):
+        ka, lane_a = _prep_keys(a, lens_a, encode=encode, flip=flip)
+        kb, lane_b = _prep_keys(b, lens_b, encode=encode, flip=flip)
+        keys, pos = merge_runs(prog, ka, kb,
+                               payload=(lane_a, wa + lane_b), use_mxu=use_mxu)
+        in_a = pos < wa
+        valid = (in_a & (pos < lens_a)) | (~in_a & (pos - wa < lens_b))
+        pos = compact_lanes(valid, [pos], use_mxu=use_mxu)[0]
+        # perm in *segment* coordinates: b elements continue at len_a
+        seg_pos = jnp.where(pos < wa, pos, lens_a + (pos - wa))
+        return _prefix_outs(pos, jnp.concatenate([a, b], axis=1), p_ins,
+                            k_out, want_perm, seg_pos=seg_pos)
+
+    for_row_slabs([a_ref, b_ref, la_ref, lb_ref, *refs[:n_payload]],
+                  refs[n_payload:], body, row_slab(a_ref.shape[0], wa + wb))
 
 
-def _class_call(kernel, inputs, payloads, *, k_out: int,
+def _n_lanes(payloads) -> int:
+    return len(split_payload_lanes(payloads)[0])
+
+
+def _class_call(kernel, inputs, payloads, *, width: int, k_out: int,
                 block_batch: int, want_perm: bool, interpret, dtype):
-    """Shared pallas_call wrapper: batch-pad, build specs, unpack."""
+    """Shared pallas_call wrapper: batch-pad, build specs, unpack.
+    ``width`` is the row width the kernel walks."""
     interpret = resolve_interpret(interpret)
     bsz = inputs[0].shape[0]
+    lanes, rebuild = split_payload_lanes(payloads)
+    block_batch = tile_rows(block_batch, bsz)
     inputs = [pad_batch(v, block_batch) for v in inputs]
-    payloads = tuple(pad_batch(p, block_batch) for p in payloads)
+    lanes = [pad_batch(p, block_batch) for p in lanes]
+    dtypes = [dtype] + [p.dtype for p in lanes]
+    n_in = len(inputs)
+    both = slab_lanes(inputs + lanes, block_batch, width)
+    inputs, lanes = both[:n_in], both[n_in:]
+    dtype = inputs[0].dtype
     padded = inputs[0].shape[0]
-    in_specs = [pl.BlockSpec((block_batch, v.shape[1]), lambda i: (i, 0))
-                for v in inputs]
-    in_specs += [payload_block_spec(p, block_batch) for p in payloads]
-    out_specs = [pl.BlockSpec((block_batch, k_out), lambda i: (i, 0))]
+    in_specs = [row_block_spec(block_batch, v.shape[1])
+                for v in inputs + lanes]
+    out_specs = [row_block_spec(block_batch, k_out)] * (
+        1 + int(want_perm) + len(lanes))
     out_shapes = [jax.ShapeDtypeStruct((padded, k_out), dtype)]
     if want_perm:
-        out_specs.append(pl.BlockSpec((block_batch, k_out), lambda i: (i, 0)))
         out_shapes.append(jax.ShapeDtypeStruct((padded, k_out), jnp.int32))
-    for p in payloads:
-        shp = (padded, k_out) + p.shape[2:]
-        out_specs.append(
-            pl.BlockSpec((block_batch, k_out) + p.shape[2:],
-                         (lambda i: (i, 0, 0)) if p.ndim == 3
-                         else (lambda i: (i, 0))))
-        out_shapes.append(jax.ShapeDtypeStruct(shp, p.dtype))
+    out_shapes += [jax.ShapeDtypeStruct((padded, k_out), p.dtype)
+                   for p in lanes]
     results = pl.pallas_call(
         kernel,
         grid=(padded // block_batch,),
@@ -167,11 +180,13 @@ def _class_call(kernel, inputs, payloads, *, k_out: int,
         out_specs=out_specs,
         out_shape=out_shapes,
         interpret=interpret,
-    )(*inputs, *payloads)
-    res = unpack_fused_results(results, bsz, padded, len(payloads), want_perm)
-    if not payloads and not want_perm:
+    )(*inputs, *lanes)
+    results = narrow_back(results, dtypes, want_perm)
+    res = unpack_fused_results(results, bsz, padded, len(lanes), want_perm)
+    if not lanes and not want_perm:
         return res, None, ()  # shared epilogue returns the bare values
-    return res
+    out, perm, pouts = res
+    return out, perm, rebuild(pouts)
 
 
 @functools.partial(
@@ -205,11 +220,11 @@ def segment_class_sort_pallas(
     encode = encode and jnp.issubdtype(dense.dtype, jnp.floating)
     kernel = functools.partial(
         _seg_sort_kernel, w=w, k_out=k_out, network=network, encode=encode,
-        flip=flip, use_mxu=use_mxu, n_payload=len(payloads),
+        flip=flip, use_mxu=use_mxu, n_payload=_n_lanes(payloads),
         want_perm=want_perm,
     )
     return _class_call(
-        kernel, [dense, lens.astype(jnp.int32)], tuple(payloads),
+        kernel, [dense, lens.astype(jnp.int32)], tuple(payloads), width=w,
         k_out=k_out, block_batch=block_batch,
         want_perm=want_perm, interpret=interpret, dtype=dense.dtype,
     )
@@ -253,12 +268,13 @@ def segment_class_merge_pallas(
     kernel = functools.partial(
         _seg_merge_kernel, wa=wa, wb=wb, k_out=k_out, network=network,
         n_cols=n_cols, encode=encode, flip=flip, use_mxu=use_mxu,
-        n_payload=len(payloads), want_perm=want_perm,
+        n_payload=_n_lanes(payloads),
+        want_perm=want_perm,
     )
     return _class_call(
         kernel,
         [dense_a, dense_b, lens_a.astype(jnp.int32), lens_b.astype(jnp.int32)],
-        tuple(payloads), k_out=k_out,
+        tuple(payloads), width=total, k_out=k_out,
         block_batch=block_batch, want_perm=want_perm, interpret=interpret,
         dtype=dense_a.dtype,
     )

@@ -18,14 +18,15 @@ merge-step program the registry hands back — the autotuner tournament
 picks the family per size class.
 
 Sentinel handling never relies on tie order: when a position lane is
-carried, validity is decided by mask (``stable_compact``); the bare
+carried, validity is decided by mask (``compact_lanes``); the bare
 values-only call needs only multiset-sortedness, under which the first
 ``n`` output slots are exactly the sorted input for *any* family.
 
-VMEM: the widest tree level materializes a (bt, npad/2, run, run)
-comparison cloud ~ bt * npad^2 / 4 f32 entries; ``streaming.planner``
-(``plan_sort`` / ``sort_fits_vmem``) sizes ``block_batch`` and gates
-routing so this stays inside the budget.
+VMEM: every level ranks and permutes through (rows, S, 128) pairwise
+tiles, S the level's group width (at least 128); the kernel walks row
+slabs (``row_slab``) so the widest tile stays near 2^17 elements whatever
+``block_batch`` is. ``streaming.planner`` (``plan_sort`` /
+``sort_fits_vmem``) gates routing by width.
 """
 from __future__ import annotations
 
@@ -41,15 +42,22 @@ from repro.networks import run_sort_program, sort_program
 from .common import (
     _iota,
     ceil_pow2,
-    decode_key_values,
-    encode_key_values,
+    compact_lanes,
+    decode_kernel_keys,
+    encode_kernel_keys,
+    for_row_slabs,
     gather_lanes,
+    narrow_back,
     np_fill,
     pad_batch,
-    payload_block_spec,
     resolve_interpret,
+    reverse_lanes,
+    row_block_spec,
+    row_slab,
+    slab_lanes,
     sentinel_max,
-    stable_compact,
+    split_payload_lanes,
+    tile_rows,
     unpack_fused_results,
 )
 
@@ -66,41 +74,44 @@ def _sort_kernel(
     want_perm: bool,
 ):
     p_refs = refs[:n_payload]
-    o_ref = refs[n_payload]
-    perm_ref = refs[n_payload + 1] if want_perm else None
-    po_refs = refs[n_payload + 1 + (1 if want_perm else 0):]
-
-    x = x_ref[...]  # (bt, n) unsorted
-    bt = x.shape[0]
-    if key_dtype is not None:  # fused nan_policy="last" encode on load
-        x = encode_key_values(x)
+    out_refs = refs[n_payload:]
     npad = ceil_pow2(n)
-    if npad != n:
-        # np_fill: a bare python uint32-max overflows weak-int32 promotion
-        fill = np_fill(sentinel_max(x.dtype), x.dtype)
-        x = jnp.pad(x, [(0, 0), (0, npad - n)], constant_values=fill)
     need_pos = n_payload > 0 or want_perm
-    pos = _iota((bt, npad), 1) if need_pos else None
-    # the unrolled merge tree comes from the network registry (shared with
-    # the segmented class kernels, column-device cutover included)
-    x, pos = run_sort_program(sort_program(network, npad), x, pos, use_mxu)
-    if need_pos and npad != n:
-        # the column devices make no cross-run tie-order promise, so a tail
-        # pad that ties a genuine dtype-max value may land inside the live
-        # prefix; validity is decided by the position lane, never by value
-        x, pos = stable_compact(pos < n, x, pos)
-    out = x[:, :n]  # value-identical under pad/max aliasing (pads tie)
-    perm = pos[:, :n].astype(jnp.int32) if need_pos else None
-    if key_dtype is not None:  # fused decode on store
-        out = decode_key_values(out, key_dtype)
-    if descending:
-        out = out[:, ::-1]
-        perm = None if perm is None else perm[:, ::-1]
-    o_ref[...] = out
-    if want_perm:
-        perm_ref[...] = perm
-    for p_ref, po_ref in zip(p_refs, po_refs):
-        po_ref[...] = gather_lanes(perm, p_ref[...])
+
+    def body(x, *leaves):
+        bt = x.shape[0]
+        if key_dtype is not None:  # fused nan_policy="last" encode on load
+            x = encode_kernel_keys(x)
+        if npad != n:
+            # np_fill: a bare python uint32-max overflows weak-int32
+            # promotion
+            fill = np_fill(sentinel_max(x.dtype), x.dtype)
+            x = jnp.concatenate(
+                [x, jnp.full((bt, npad - n), fill, x.dtype)], axis=-1)
+        pos = _iota((bt, npad), 1) if need_pos else None
+        # the unrolled merge tree comes from the network registry (shared
+        # with the segmented class kernels, column-device cutover included)
+        x, pos = run_sort_program(sort_program(network, npad), x, pos,
+                                  use_mxu)
+        if need_pos and npad != n:
+            # the column devices make no cross-run tie-order promise, so a
+            # tail pad that ties a genuine dtype-max value may land inside
+            # the live prefix; validity is decided by the position lane,
+            # never by value
+            x, pos = compact_lanes(pos < n, [x, pos], use_mxu=use_mxu)
+        out = x[:, :n]  # value-identical under pad/max aliasing (pads tie)
+        perm = pos[:, :n] if need_pos else None
+        if key_dtype is not None:  # fused decode on store
+            out = decode_kernel_keys(out, key_dtype)
+        if descending:
+            out, *rest = reverse_lanes([out] + ([perm] if need_pos else []),
+                                       use_mxu=use_mxu)
+            perm = rest[0] if need_pos else None
+        outs = [out] + ([perm] if want_perm else [])
+        return outs + [gather_lanes(perm, leaf) for leaf in leaves]
+
+    for_row_slabs([x_ref, *p_refs], out_refs, body,
+                  row_slab(x_ref.shape[0], npad))
 
 
 @functools.partial(
@@ -142,29 +153,34 @@ def loms_sort_pallas(
     payloads = tuple(payloads)
     for p in payloads:
         assert p.ndim in (2, 3) and p.shape[:2] == (bsz, n), (p.shape, (bsz, n))
+    lanes, rebuild = split_payload_lanes(payloads)
+    block_batch = tile_rows(block_batch, bsz)
     x = pad_batch(x, block_batch)
-    payloads_p = tuple(pad_batch(p, block_batch) for p in payloads)
+    lanes = [pad_batch(p, block_batch) for p in lanes]
+    dtypes = [x.dtype] + [p.dtype for p in lanes]
+    x, *lanes = slab_lanes([x, *lanes], block_batch, ceil_pow2(n))
     padded = x.shape[0]
-    out_specs = [pl.BlockSpec((block_batch, n), lambda i: (i, 0))]
+    spec = row_block_spec(block_batch, n)
+    out_specs = [spec] * (1 + int(want_perm) + len(lanes))
     out_shapes = [jax.ShapeDtypeStruct((padded, n), x.dtype)]
     if want_perm:
-        out_specs.append(pl.BlockSpec((block_batch, n), lambda i: (i, 0)))
         out_shapes.append(jax.ShapeDtypeStruct((padded, n), jnp.int32))
-    out_specs += [payload_block_spec(p, block_batch) for p in payloads_p]
-    out_shapes += [jax.ShapeDtypeStruct(p.shape, p.dtype) for p in payloads_p]
+    out_shapes += [jax.ShapeDtypeStruct(p.shape, p.dtype) for p in lanes]
     results = pl.pallas_call(
         functools.partial(
             _sort_kernel, n=n, network=network, use_mxu=use_mxu,
             key_dtype=key_dtype, descending=descending,
-            n_payload=len(payloads), want_perm=want_perm,
+            n_payload=len(lanes), want_perm=want_perm,
         ),
         grid=(padded // block_batch,),
-        in_specs=[
-            pl.BlockSpec((block_batch, n), lambda i: (i, 0)),
-            *[payload_block_spec(p, block_batch) for p in payloads_p],
-        ],
+        in_specs=[spec] * (1 + len(lanes)),
         out_specs=out_specs,
         out_shape=out_shapes,
         interpret=interpret,
-    )(x, *payloads_p)
-    return unpack_fused_results(results, bsz, padded, len(payloads), want_perm)
+    )(x, *lanes)
+    results = narrow_back(results, dtypes, want_perm)
+    res = unpack_fused_results(results, bsz, padded, len(lanes), want_perm)
+    if not payloads and not want_perm:
+        return res
+    out, perm, pouts = res
+    return out, perm, rebuild(pouts)

@@ -10,11 +10,36 @@ per row to running the unpadded prompt alone.
 
 Engine mode: ``--engine`` routes the same request mix through the
 continuous-batching scheduler (admission queue, paged KV-cache slots,
-disaggregated prefill/decode) instead of one-shot ``generate()``.
+disaggregated prefill/decode) instead of one-shot ``generate()``. The
+command exits non-zero, naming the error, when any request ends FAILED
+or TIMED_OUT.
 """
 from __future__ import annotations
 
 import argparse
+import math
+import sys
+
+
+def build_engine(params, cfg, *, slots: int, page_size: int,
+                 prompt_len: int, new_tokens: int):
+    """The scheduler ``--engine`` serves through: ``slots`` paged slots,
+    each with pages for ``prompt_len + new_tokens`` positions."""
+    from repro.serving.scheduler import ScheduledEngine, SchedulerConfig
+
+    pages = math.ceil((prompt_len + new_tokens) / page_size)
+    return ScheduledEngine(params, cfg, SchedulerConfig(
+        n_slots=slots, page_size=page_size, pages_per_slot=pages))
+
+
+def unfinished(eng) -> list:
+    """``(rid, state, error)`` of every request that ended FAILED or
+    TIMED_OUT."""
+    from repro.serving.scheduler.request import RequestState
+
+    bad = (RequestState.FAILED, RequestState.TIMED_OUT)
+    return [(r.rid, r.state.value, r.error)
+            for r in eng.requests.values() if r.state in bad]
 
 
 def main():
@@ -41,10 +66,12 @@ def main():
     import jax.numpy as jnp
     import numpy as np
 
+    from repro.compile_cache import enable_compile_cache
     from repro.configs import get_config, get_smoke_config
     from repro.models import model_init
     from repro.serving.engine import ServeConfig, generate
 
+    enable_compile_cache()
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     assert not cfg.is_encoder_only, f"{cfg.name} is encoder-only: no decode"
     params, _ = model_init(jax.random.PRNGKey(0), cfg)
@@ -61,18 +88,16 @@ def main():
             tokens[r, n:] = 0
 
     if args.engine:
-        from repro.serving.scheduler import (
-            SamplingParams, ScheduledEngine, SchedulerConfig)
+        from repro.serving.scheduler import SamplingParams
 
         assert cfg.family == "dense", \
             "--engine bit-equality contract covers dense stacks"
         lens = lengths if lengths is not None \
             else np.full(args.batch, args.prompt_len, np.int32)
-        import math
-        pages = math.ceil((args.prompt_len + args.new_tokens) / args.page_size)
-        eng = ScheduledEngine(params, cfg, SchedulerConfig(
-            n_slots=args.slots, page_size=args.page_size,
-            pages_per_slot=pages))
+        eng = build_engine(params, cfg, slots=args.slots,
+                           page_size=args.page_size,
+                           prompt_len=args.prompt_len,
+                           new_tokens=args.new_tokens)
         rids = [eng.submit(tokens[r, :lens[r]],
                            SamplingParams(k=args.top_k, top_p=args.top_p,
                                           temperature=args.temperature,
@@ -81,6 +106,13 @@ def main():
                            arrival=r)
                 for r in range(args.batch)]
         out = eng.run()
+        bad = unfinished(eng)
+        if bad:
+            for rid, state, err in bad:
+                print(f"[serve] request {rid} {state}: {err}",
+                      file=sys.stderr)
+            sys.exit(f"[serve] {len(bad)} of {len(rids)} requests did not "
+                     f"finish; first error: {bad[0][2]}")
         print(f"[serve] engine drained {len(out)} requests in {eng.t} ticks "
               f"({args.slots} slots, page {args.page_size})")
         for rid in rids[:2]:

@@ -27,11 +27,13 @@ def main():
     ap.add_argument("--data-bin", default=None)
     args = ap.parse_args()
 
+    from repro.compile_cache import enable_compile_cache
     from repro.configs import get_config, get_smoke_config
     from repro.data import DataConfig
     from repro.optim import OptConfig
     from repro.runtime import TrainConfig, train_with_retries
 
+    enable_compile_cache()
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     dc = DataConfig(seq_len=args.seq, global_batch=args.batch,
                     bin_path=args.data_bin)

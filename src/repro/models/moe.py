@@ -296,10 +296,10 @@ def moe_apply(p: Params, x: jnp.ndarray, cfg: ModelConfig, par=None):
     for name in ("wi", "wg", "wo"):
         pspecs[name] = {"w": P(tp)}  # experts sharded over the TP axis
     x_spec = P(dp, tp, None) if seq_shardable else P(dp, None, None)
-    from repro.parallel.sharding import shard_map_compat
 
-    return shard_map_compat(
-        body, mesh,
+    return jax.shard_map(
+        body, mesh=mesh,
         in_specs=(x_spec, pspecs),
         out_specs=x_spec,
+        check_vma=False,
     )(x, p)

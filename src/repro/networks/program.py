@@ -17,9 +17,10 @@ in :mod:`repro.networks.families` and handed to kernels through
 :mod:`repro.networks.registry` — kernels never import a generator
 directly, so tie-order and cutover behavior live in exactly one place.
 The executors here (:func:`merge_runs`, :func:`run_sort_program`) are
-plain ``jnp`` on the last axis — safe inside Pallas kernel bodies (no
-captured numpy index constants; only reshapes, static slices, reversals
-and :func:`repro.kernels.common._iota`).
+plain ``jnp`` on the last axis built from the lane primitives of
+:mod:`repro.kernels.common` (ranks, one-hot permutes, static rolls) —
+safe inside Pallas kernel bodies in interpret mode and on the TPU (no
+captured numpy constants, gathers, reversals or strided views).
 
 :func:`program_to_schedule` lifts a program back into the validated
 ``Schedule`` IR so the 0-1-principle checkers in ``core.networks`` apply
@@ -38,7 +39,8 @@ from typing import Optional, Tuple
 
 import jax.numpy as jnp
 
-from repro.kernels.common import _iota, merge2_sorted, sort_nsorter
+from repro.kernels.common import (_iota, group_window, permute_lanes, run_rank,
+                                  widen)
 
 __all__ = [
     "PairStage",
@@ -119,95 +121,100 @@ class SortProgram:
 # ---------------------------------------------------------------------------
 
 
-def _xor_exchange(x, p, d: int):
-    """Compare-exchange lanes (i, i^d) on the last axis (2*d | L)."""
-    lead, L = x.shape[:-1], x.shape[-1]
-    y = x.reshape(lead + (L // (2 * d), 2, d))
-    a, b = y[..., 0, :], y[..., 1, :]
-    swap = a > b
-    out = jnp.stack([jnp.where(swap, b, a), jnp.where(swap, a, b)],
-                    axis=-2).reshape(lead + (L,))
-    if p is None:
-        return out, None
-    q = p.reshape(lead + (L // (2 * d), 2, d))
-    pa, pb = q[..., 0, :], q[..., 1, :]
-    pout = jnp.stack([jnp.where(swap, pb, pa), jnp.where(swap, pa, pb)],
-                     axis=-2).reshape(lead + (L,))
-    return out, pout
+def _partner(x, lane_bit, shift: int):
+    """Value of lane ``i + shift`` where ``lane_bit`` is False and of lane
+    ``i - shift`` where it is True (static rolls: slices + a concat)."""
+    return jnp.where(lane_bit, jnp.roll(x, shift, axis=-1),
+                     jnp.roll(x, -shift, axis=-1))
 
 
-def _apply_pair_stage(st: PairStage, x, p):
-    L = x.shape[-1]
+def _exchange(arrays, partner_of, lower, active):
+    """Compare-exchange every active lane with its partner: the min lands
+    on the lower lane. Both lanes of a pair evaluate the same strict
+    comparison, so the swap is self-consistent under ties, and payload
+    lanes follow the key swap."""
+    keys = arrays[0]
+    other = partner_of(keys)
+    k, o = widen(keys), widen(other)
+    swap = active & ((lower & (k > o)) | (~lower & (o > k)))
+    out = [jnp.where(swap, other, keys)]
+    for a in arrays[1:]:
+        out.append(jnp.where(swap, partner_of(a), a))
+    return out
+
+
+def _pair_stage(st: PairStage, arrays, gw: int, use_mxu: bool):
+    """One compare-exchange stage over groups of ``gw`` lanes."""
+    width = arrays[0].shape[-1]
+    lane = _iota((1, width), 1)
+    t = lane % gw
     if st.kind == "xor":
-        return _xor_exchange(x, p, st.d)
+        upper = (t & st.d) != 0
+        return _exchange(arrays, lambda a: _partner(a, upper, st.d),
+                         ~upper, True)
     if st.kind == "reflect":
-        # lanes i and L-1-i; both halves evaluate the same strict
-        # comparison so the swap mask is self-consistent under ties
-        r = x[..., ::-1]
-        left = _iota(x.shape, x.ndim - 1) < (L // 2)
-        swap = jnp.where(left, x > r, r > x)
-        out = jnp.where(swap, r, x)
-        if p is None:
-            return out, None
-        return out, jnp.where(swap, p[..., ::-1], p)
+        # lanes i and gw-1-i: the partner value is the group's reversal
+        dest = lane - t + (gw - 1 - t)
+
+        def partner_of(a):
+            return permute_lanes(dest, [a], width, use_mxu=use_mxu,
+                                 window=group_window(gw, width))[0]
+
+        return _exchange(arrays, partner_of, t < gw // 2, True)
     assert st.kind == "brick_odd"
-    if L <= 2:
-        return x, p
-    head, mid, tail = x[..., :1], x[..., 1:L - 1], x[..., L - 1:]
-    pm = None if p is None else p[..., 1:L - 1]
-    mid, pm = _xor_exchange(mid, pm, 1)
-    out = jnp.concatenate([head, mid, tail], axis=-1)
-    if p is None:
-        return out, None
-    pout = jnp.concatenate([p[..., :1], pm, p[..., L - 1:]], axis=-1)
-    return out, pout
+    if gw <= 2:
+        return arrays
+    lower = (t % 2) == 1
+    active = (t >= 1) & (t <= gw - 2)
+    return _exchange(arrays, lambda a: _partner(a, ~lower, 1), lower, active)
 
 
-def _merge_columns(prog: MergeProgram, lo, hi, payload, use_mxu: bool):
-    """The paper's UP-m/DN-n column device as strided views: column ``c``
-    holds the ascending stride-C slices ``lo[c::C]`` and
-    ``hi[(C-1-c)%C::C]``, each column is one S2MS merge (``m*n/C^2``
-    comparators instead of the plain S2MS ``m*n``), stage 2 rank-sorts
-    each row of C values."""
+def _merge_level(prog: MergeProgram, arrays, gw: int, use_mxu: bool):
+    """Run ``prog`` on every group of ``gw = m + n`` lanes, each laid out
+    ``[lo | hi]``; ``arrays[0]`` are the keys, the rest ride along. One
+    lane-flat pass for all groups: no reshape, strided view or reversal
+    reaches the kernel, so the same code runs in interpret mode and on
+    the TPU."""
     m, n = prog.m, prog.n
+    assert gw == m + n, (gw, prog)
+    keys = arrays[0]
+    width = keys.shape[-1]
+    lane = _iota((1, width), 1)
+    single = gw == width
+    t = lane if single else lane % gw
+    base = lane - t
+    hi = t >= m
+    win = None if single else group_window(gw, width)
+    if prog.kind == "pairs":
+        out = list(arrays)
+        if prog.reverse_hi:
+            rev = jnp.where(hi, base + m + (gw - 1 - t), lane)
+            out = permute_lanes(rev, out, width, window=win, use_mxu=use_mxu)
+        for st in prog.stages:
+            out = _pair_stage(st, out, gw, use_mxu)
+        return out
     c_ = prog.n_cols
+    grp = None if single else lane // gw
     if c_ <= 1 or m % c_ or n % c_:
-        return merge2_sorted(lo, hi, payload=payload, use_mxu=use_mxu)
-    plo, phi = payload if payload is not None else (None, None)
-    cols, pcols = [], []
-    for c in range(c_):
-        av = lo[..., c::c_]
-        bv = hi[..., (c_ - 1 - c) % c_ :: c_]
-        if payload is not None:
-            col, pcol = merge2_sorted(
-                bv, av,
-                payload=(phi[..., (c_ - 1 - c) % c_ :: c_], plo[..., c::c_]),
-                use_mxu=use_mxu,
-            )
-            pcols.append(pcol)
-        else:
-            col = merge2_sorted(bv, av, use_mxu=use_mxu)
-        cols.append(col)
-    arr = jnp.stack(cols, axis=-1)  # (..., R, C)
-    shape = lo.shape[:-1] + (m + n,)
-    if payload is not None:
-        arr, parr = sort_nsorter(arr, jnp.stack(pcols, axis=-1),
-                                 use_mxu=use_mxu)
-        return arr.reshape(shape), parr.reshape(shape)
-    return sort_nsorter(arr, use_mxu=use_mxu).reshape(shape)
-
-
-def _merge_pairs(prog: MergeProgram, lo, hi, payload):
-    hi_ = hi[..., ::-1] if prog.reverse_hi else hi
-    x = jnp.concatenate([lo, hi_], axis=-1)
-    p = None
-    if payload is not None:
-        plo, phi = payload
-        p = jnp.concatenate(
-            [plo, phi[..., ::-1] if prog.reverse_hi else phi], axis=-1)
-    for st in prog.stages:
-        x, p = _apply_pair_stage(st, x, p)
-    return (x, p) if payload is not None else x
+        # S2MS: one stable rank-merge of the two runs (lo wins ties)
+        run = hi.astype(jnp.int32)
+        rank = run_rank(keys, grp, run, t - run * m, window=win)
+        return permute_lanes(base + rank, arrays, width, window=win,
+                             use_mxu=use_mxu)
+    # the paper's UP-m/DN-n column device, in lane coordinates: lo lane i
+    # sits in column i % C, hi lane j in column (C-1-j) % C, each at row
+    # position //C. Stage 1 merges every column (the hi run wins ties, as
+    # the column device lists it first); the merged position is the row.
+    # Stage 2 ranks each row of C values, ties by column; the output is
+    # the row-major flatten.
+    i = jnp.where(hi, t - m, t)
+    col = jnp.where(hi, (c_ - 1) - i % c_, i % c_)
+    g0 = 0 if single else grp
+    row = run_rank(keys, g0 * c_ + col, jnp.where(hi, 0, 1), i // c_,
+                   window=win)
+    rank2 = run_rank(keys, g0 * (gw // c_) + row, col, 0, window=win)
+    return permute_lanes(base + row * c_ + rank2, arrays, width, window=win,
+                         use_mxu=use_mxu)
 
 
 def merge_runs(
@@ -219,12 +226,18 @@ def merge_runs(
     use_mxu: bool = True,
 ):
     """Execute one merge program on ascending runs ``lo``/``hi`` (last
-    axis). With ``payload=(plo, phi)`` returns ``(vals, pvals)``."""
+    axis, any leading shape). With ``payload=(plo, phi)`` returns
+    ``(vals, pvals)``."""
     assert lo.shape[-1] == prog.m and hi.shape[-1] == prog.n, (
         lo.shape, hi.shape, prog)
-    if prog.kind == "columns":
-        return _merge_columns(prog, lo, hi, payload, use_mxu)
-    return _merge_pairs(prog, lo, hi, payload)
+    lead = lo.shape[:-1]
+    total = prog.m + prog.n
+    arrays = [jnp.concatenate([lo, hi], axis=-1).reshape(-1, total)]
+    if payload is not None:
+        arrays.append(jnp.concatenate(payload, axis=-1).reshape(-1, total))
+    out = [o.reshape(lead + (total,))
+           for o in _merge_level(prog, arrays, total, use_mxu)]
+    return (out[0], out[1]) if payload is not None else out[0]
 
 
 def run_sort_program(prog: SortProgram, keys: jnp.ndarray,
@@ -236,25 +249,12 @@ def run_sort_program(prog: SortProgram, keys: jnp.ndarray,
     structure (e.g. the LOMS column-device cutover, chosen by the family
     generator) and tie-order behavior can never diverge between them.
     Returns ``(keys, pos)``."""
-    bt = keys.shape[0]
     w = prog.width
     assert keys.shape[-1] == w, (keys.shape, w)
+    arrays = [keys] if pos is None else [keys, pos]
     for mp in prog.levels:
-        run = mp.m
-        g = w // (2 * run)
-        kv = keys.reshape(bt, g, 2 * run)
-        if pos is not None:
-            pv = pos.reshape(bt, g, 2 * run)
-            kv, pv = merge_runs(
-                mp, kv[..., :run], kv[..., run:],
-                payload=(pv[..., :run], pv[..., run:]), use_mxu=use_mxu,
-            )
-            pos = pv.reshape(bt, w)
-        else:
-            kv = merge_runs(mp, kv[..., :run], kv[..., run:],
-                            use_mxu=use_mxu)
-        keys = kv.reshape(bt, w)
-    return keys, pos
+        arrays = _merge_level(mp, arrays, 2 * mp.m, use_mxu)
+    return arrays[0], (None if pos is None else arrays[1])
 
 
 # ---------------------------------------------------------------------------

@@ -6,7 +6,6 @@ from .sharding import (  # noqa: F401
     cache_pspecs,
     dist_sort_axis,
     make_parallelism,
-    shard_map_compat,
     to_named,
     vocab_topk_axis,
 )

@@ -270,7 +270,6 @@ def _fill_for(dtype):
 @functools.partial(jax.jit, static_argnames=("mesh", "axis_name", "with_pos"))
 def _sample_sort_jit(x, pos, *, mesh, axis_name, with_pos):
     from repro.api import schedules
-    from repro.parallel.sharding import shard_map_compat
 
     p = int(mesh.shape[axis_name])
     fill = _fill_for(x.dtype)
@@ -282,14 +281,15 @@ def _sample_sort_jit(x, pos, *, mesh, axis_name, with_pos):
                                 axis_name=axis_name, p=p, fill=fill)
             return out
 
-        return shard_map_compat(body, mesh, in_specs=spec, out_specs=spec)(x)
+        return jax.shard_map(body, mesh=mesh, in_specs=spec, out_specs=spec,
+                             check_vma=False)(x)
 
     def body(xl, pl):
         xs, psl = schedules.sort(xl, payload=pl)
         return _psrs_tail(xs, psl, axis_name=axis_name, p=p, fill=fill)
 
-    return shard_map_compat(body, mesh, in_specs=(spec, spec),
-                            out_specs=(spec, spec))(x, pos)
+    return jax.shard_map(body, mesh=mesh, in_specs=(spec, spec),
+                         out_specs=(spec, spec), check_vma=False)(x, pos)
 
 
 def sample_sort(
@@ -314,7 +314,6 @@ def sample_sort(
 @functools.partial(jax.jit,
                    static_argnames=("mesh", "axis_name", "k", "with_pos"))
 def _sample_merge_jit(*arrs, mesh, axis_name, k, with_pos):
-    from repro.parallel.sharding import shard_map_compat
 
     p = int(mesh.shape[axis_name])
     fill = _fill_for(arrs[0].dtype)
@@ -327,15 +326,16 @@ def _sample_merge_jit(*arrs, mesh, axis_name, k, with_pos):
                                 fill=fill)
             return out
 
-        return shard_map_compat(body, mesh, in_specs=tuple(spec for _ in arrs),
-                                out_specs=spec)(*arrs)
+        return jax.shard_map(body, mesh=mesh,
+                             in_specs=tuple(spec for _ in arrs),
+                             out_specs=spec, check_vma=False)(*arrs)
 
     def body(*args):
         merged, pmerged = _merge_sorted_runs(list(args[:k]), list(args[k:]))
         return _psrs_tail(merged, pmerged, axis_name=axis_name, p=p, fill=fill)
 
-    return shard_map_compat(body, mesh, in_specs=tuple(spec for _ in arrs),
-                            out_specs=(spec, spec))(*arrs)
+    return jax.shard_map(body, mesh=mesh, in_specs=tuple(spec for _ in arrs),
+                         out_specs=(spec, spec), check_vma=False)(*arrs)
 
 
 def sample_merge_k(

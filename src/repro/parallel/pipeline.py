@@ -69,10 +69,10 @@ def pipeline_apply(
         jax.tree.map(lambda _: P(axis), stage_params),
         P(None),  # every rank sees all microbatches (input broadcast)
     )
-    from .sharding import shard_map_compat
 
-    out = shard_map_compat(body, mesh, in_specs=in_specs,
-                           out_specs=P(axis, None))(stage_params, x)
+    out = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                        out_specs=P(axis, None),
+                        check_vma=False)(stage_params, x)
     # out is (pipe, n_micro/..., ...) — only the last stage's slice holds
     # real outputs; gather it
     return out.reshape((n_stages, n_micro) + x.shape[1:])[-1]

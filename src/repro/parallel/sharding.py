@@ -55,23 +55,6 @@ class Parallelism:
             x, NamedSharding(self.mesh, P(*dims)))
 
 
-def shard_map_compat(f, mesh: Mesh, in_specs, out_specs, check: bool = False):
-    """``jax.shard_map`` across jax versions: new releases expose it at the
-    top level with ``check_vma``; 0.4.x has ``jax.experimental.shard_map``
-    with ``check_rep``."""
-    if hasattr(jax, "shard_map"):
-        try:
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_vma=check)
-        except TypeError:
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_rep=check)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check)
-
-
 def dist_sort_axis(par: Optional[Parallelism], lengths) -> Optional[str]:
     """Mesh axis for the distributed sample-sort (parallel.dist_sort), or
     None when the lists cannot shard evenly over the TP axis — every input

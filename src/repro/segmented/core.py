@@ -540,7 +540,9 @@ def segment_topk_impl(
     lengths = segment_lengths(offs)
     ks = _normalize_ks(k, len(offs) - 1)
     counts = [min(k_s, int(ln)) for k_s, ln in zip(ks, lengths)]
-    out_offs = tuple(np.concatenate([[0], np.cumsum(counts)]).tolist())
+    # int dtype even with zero segments (np.cumsum([]) is float64)
+    out_offs = tuple(np.concatenate(
+        [[0], np.cumsum(counts, dtype=np.int64)]).astype(int).tolist())
     total = out_offs[-1]
 
     lanes, rebuild = ([], None)

@@ -21,7 +21,10 @@ meaning across releases, stale entries silently degrade to a heuristic
 re-plan instead of mis-parameterizing a kernel.
 
 The default location is ``$REPRO_AUTOTUNE_CACHE`` or
-``~/.cache/repro_loms/autotune.json``.
+``<checkout>/.autotune/autotune.json`` (gitignored), so a run reads and
+writes nothing outside its checkout. Keys carry the device kind, not
+just the platform: a tile tuned on one TPU generation is never replayed
+on another.
 """
 from __future__ import annotations
 
@@ -50,9 +53,18 @@ def default_cache_path() -> str:
     env = os.environ.get("REPRO_AUTOTUNE_CACHE")
     if env:
         return env
-    return os.path.join(
-        os.path.expanduser("~"), ".cache", "repro_loms", "autotune.json"
-    )
+    from repro.compile_cache import checkout_root
+
+    return os.path.join(checkout_root(), ".autotune", "autotune.json")
+
+
+def device_tag() -> str:
+    """``platform:device_kind`` of the first device (``tpu:TPU v5 lite``,
+    ``cpu:cpu``) — the backend part of every tuning and route key."""
+    import jax
+
+    d = jax.devices()[0]
+    return f"{d.platform}:{d.device_kind}"
 
 
 def plan_key(op: str, *, shapes, dtype, k: Optional[int] = None,
@@ -60,12 +72,11 @@ def plan_key(op: str, *, shapes, dtype, k: Optional[int] = None,
     """Stable string key for one tuning point.
 
     ``shapes`` is any nested int structure (list lengths + batch); ``k`` the
-    truncation (top-k) if any; ``backend`` defaults to the active JAX
-    backend so TPU and CPU-interpret timings never mix."""
+    truncation (top-k) if any; ``backend`` defaults to the
+    :func:`device_tag`, so timings from CPU interpret mode and from each
+    TPU generation never mix."""
     if backend is None:
-        import jax
-
-        backend = jax.default_backend()
+        backend = device_tag()
     shp = "x".join(str(int(s)) for s in _flat_ints(shapes))
     return f"{op}|{shp}|k{k if k is not None else '-'}|{dtype}|{backend}"
 

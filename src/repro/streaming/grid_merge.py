@@ -13,7 +13,7 @@ This kernel keeps the whole pipeline resident for the duration of one
 * the carry tile lives in **VMEM scratch** and persists across grid
   steps (Pallas scratch is allocated once per launch, not per step);
 * the stream pointers and last-loaded values live in **SMEM scratch**;
-* the inputs stay in HBM/ANY and each refill is one async DMA of a single
+* the inputs stay in HBM and each refill is one async DMA of a single
   tile, chosen by the FLiMS rule (refill whichever stream's *last loaded*
   element is smaller — the bound that makes a fixed emission rate safe);
 * only the emitted lower halves are written back, through the blocked
@@ -47,24 +47,32 @@ def _grid_merge2_kernel(
     r = pl.program_id(0)
     i = pl.program_id(1)
 
+    def load(src, start, slot):
+        """DMA one tile of row ``r`` into buffer ``slot``."""
+        if not isinstance(start, int):  # stream pointers step by whole tiles
+            start = pl.multiple_of(start, t)
+        cp = pltpu.make_async_copy(src.at[r, :, pl.ds(start, t)],
+                                   buf_ref.at[slot], sem)
+        cp.start()
+        cp.wait()
+
+    def tail(slot):
+        # a vector load, then one static element: the TPU reads scalars
+        # from VMEM only this way
+        return buf_ref[slot][0, t - 1]
+
     @pl.when(i == 0)
     def _prologue():
         # load the first tile of each stream, emit the lower half
-        cp = pltpu.make_async_copy(a_hbm.at[r, pl.ds(0, t)], buf_ref.at[0], sem)
-        cp.start()
-        cp.wait()
-        cp = pltpu.make_async_copy(b_hbm.at[r, pl.ds(0, t)], buf_ref.at[1], sem)
-        cp.start()
-        cp.wait()
-        ta = buf_ref[0][None, :]
-        tb = buf_ref[1][None, :]
-        merged = merge_runs(prog, ta, tb, use_mxu=use_mxu)
+        load(a_hbm, 0, 0)
+        load(b_hbm, 0, 1)
+        merged = merge_runs(prog, buf_ref[0], buf_ref[1], use_mxu=use_mxu)
         o_ref[...] = merged[:, :t]
         carry_ref[...] = merged[:, t:]
         ptr_ref[0] = t
         ptr_ref[1] = t
-        last_ref[0] = buf_ref[0, t - 1]
-        last_ref[1] = buf_ref[1, t - 1]
+        last_ref[0] = tail(0)
+        last_ref[1] = tail(1)
 
     @pl.when(i > 0)
     def _step():
@@ -76,27 +84,21 @@ def _grid_merge2_kernel(
 
         @pl.when(sel_a)
         def _():
-            cp = pltpu.make_async_copy(
-                a_hbm.at[r, pl.ds(pa, t)], buf_ref.at[0], sem)
-            cp.start()
-            cp.wait()
+            load(a_hbm, pa, 0)
 
         @pl.when(jnp.logical_not(sel_a))
         def _():
-            cp = pltpu.make_async_copy(
-                b_hbm.at[r, pl.ds(pb, t)], buf_ref.at[0], sem)
-            cp.start()
-            cp.wait()
+            load(b_hbm, pb, 0)
 
-        cur = buf_ref[0][None, :]
-        tail = buf_ref[0, t - 1]
-        last_ref[0] = jnp.where(sel_a, tail, last_a)
-        last_ref[1] = jnp.where(sel_a, last_b, tail)
+        new = tail(0)
+        last_ref[0] = jnp.where(sel_a, new, last_a)
+        last_ref[1] = jnp.where(sel_a, last_b, new)
         # pointers clamp at the all-sentinel drain tile, so an exhausted
         # stream reads sentinels forever
         ptr_ref[0] = jnp.where(sel_a, jnp.minimum(pa + t, la - t), pa)
         ptr_ref[1] = jnp.where(sel_a, pb, jnp.minimum(pb + t, lb - t))
-        merged = merge_runs(prog, carry_ref[...], cur, use_mxu=use_mxu)
+        merged = merge_runs(prog, carry_ref[...], buf_ref[0],
+                            use_mxu=use_mxu)
         o_ref[...] = merged[:, :t]
         carry_ref[...] = merged[:, t:]
 
@@ -120,7 +122,8 @@ def grid_chunked_merge2(
     carry the finite dtype +sentinel; see chunked.py on aliasing).
     ``network`` names the registered family executing each tile merge —
     the program is built outside the kernel, a static trace-time
-    constant."""
+    constant. Sub-32-bit values merge widened to 32 bits (the SMEM
+    scalars the refill rule reads are 32-bit), which is exact."""
     from repro.resilience.failpoints import failpoint
 
     # trace-time seam: fires when this signature (re)compiles, the same
@@ -128,6 +131,10 @@ def grid_chunked_merge2(
     # executables are past the point this layer can observe
     failpoint("grid_merge.refill")
     interpret = resolve_interpret(interpret)
+    dtype = a.dtype
+    if jnp.dtype(dtype).itemsize < 4:
+        wide = jnp.float32 if jnp.issubdtype(dtype, jnp.floating) else jnp.int32
+        a, b = a.astype(wide), b.astype(wide)
     bsz, na = a.shape
     nb = b.shape[-1]
     t = int(tile)
@@ -143,26 +150,31 @@ def grid_chunked_merge2(
     # each stream gets one all-sentinel drain tile past its (padded) tail
     la = (-(-na // t) + 1) * t
     lb = (-(-nb // t) + 1) * t
-    ap = pad_tail_sorted(a, la)
-    bp = pad_tail_sorted(b, lb)
+    # rows as (1, L) slabs in HBM: a row's DMA is then a whole-dimension
+    # slice, which the TPU tiling accepts at any row
+    ap = pad_tail_sorted(a, la).reshape(bsz, 1, la)
+    bp = pad_tail_sorted(b, lb).reshape(bsz, 1, lb)
     out = pl.pallas_call(
         functools.partial(_grid_merge2_kernel, t=t, la=la, lb=lb,
                           prog=merge_program(network, t, t),
                           use_mxu=use_mxu),
         grid=(bsz, out_tiles),
+        # inputs stay in HBM: the kernel DMAs its own tiles
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pltpu.HBM),
+            pl.BlockSpec(memory_space=pltpu.HBM),
         ],
-        out_specs=pl.BlockSpec((1, t), lambda r, i: (r, i)),
-        out_shape=jax.ShapeDtypeStruct((bsz, out_tiles * t), a.dtype),
+        # one (1, t) output tile per step: the block's last two dims are
+        # a whole dimension and a lane multiple, as the TPU tiling needs
+        out_specs=pl.BlockSpec((None, None, 1, t), lambda r, i: (r, i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((bsz, out_tiles, 1, t), a.dtype),
         scratch_shapes=[
-            pltpu.VMEM((1, t), a.dtype),   # carry (resident across steps)
-            pltpu.VMEM((2, t), a.dtype),   # refill buffers
-            pltpu.SMEM((2,), jnp.int32),   # stream pointers
-            pltpu.SMEM((2,), a.dtype),     # last-loaded values
+            pltpu.VMEM((1, t), a.dtype),     # carry (resident across steps)
+            pltpu.VMEM((2, 1, t), a.dtype),  # refill buffers
+            pltpu.SMEM((2,), jnp.int32),     # stream pointers
+            pltpu.SMEM((2,), a.dtype),       # last-loaded values
             pltpu.SemaphoreType.DMA,
         ],
         interpret=interpret,
     )(ap, bp)
-    return out[:, :total]
+    return out.reshape(bsz, out_tiles * t)[:, :total].astype(dtype)

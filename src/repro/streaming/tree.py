@@ -14,9 +14,10 @@ with the tree edges mapped onto inter-device links:
 * any other axis size: one ``lax.all_gather`` of the k-candidate lists
   followed by the same log-depth merge tree computed redundantly per shard.
 
-Everything inside the ``shard_map`` body is plain jnp built from the same
-comparison-cloud/one-hot primitives the Pallas kernels use, so it traces
-under manual sharding on any backend.
+Everything inside the ``shard_map`` body is plain XLA: the same
+comparison clouds and tie rules the Pallas kernels use, with an XLA
+scatter as the permute, so it traces under manual sharding on any backend
+and moves values exactly.
 """
 from __future__ import annotations
 
@@ -26,31 +27,44 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.kernels.common import (
-    merge2_sorted,
-    sentinel_min,
-    sort_nsorter,
-    use_mxu_for,
-)
+from repro.kernels.common import sentinel_min
 
 
-def _resolve_mxu(use_mxu: Optional[bool], dtype) -> bool:
-    """``use_mxu=None`` -> by dtype (kernels.common.use_mxu_for): int
-    values would overflow the f32 one-hot matmul mantissa."""
-    if use_mxu is None:
-        return use_mxu_for(dtype)
-    return bool(use_mxu)
+def _permute(rank, *arrays):
+    """``out[..., rank[i]] = a[..., i]`` for each array: an XLA scatter,
+    exact for every dtype (this module never runs inside a kernel)."""
+    return tuple(jnp.put_along_axis(jnp.zeros_like(a), rank, a, axis=-1,
+                                    inplace=False) for a in arrays)
 
 
-def _merge_desc(av, ai, bv, bi, keep: int, use_mxu: bool):
-    """Merge two descending (value, index) lists, keep the top ``keep``."""
-    mv, mi = merge2_sorted(av[..., ::-1], bv[..., ::-1],
-                           payload=(ai[..., ::-1], bi[..., ::-1]),
-                           use_mxu=use_mxu)
-    return mv[..., ::-1][..., :keep], mi[..., ::-1][..., :keep]
+def _iota(n: int):
+    return jnp.arange(n, dtype=jnp.int32)
 
 
-def _tree_reduce_desc(vs, is_, k: int, use_mxu: bool):
+def _sort_desc(x, idx):
+    """Descending N-sorter on the last axis, ties by the higher position
+    first — the order of the Pallas kernels (the reverse of a stable
+    ascending sort)."""
+    n = x.shape[-1]
+    q, p = _iota(n)[:, None], _iota(n)[None, :]
+    xq, xp = x[..., :, None], x[..., None, :]
+    rank = ((xq > xp) | ((xq == xp) & (q > p))).sum(-2).astype(jnp.int32)
+    return _permute(rank, x, idx)
+
+
+def _merge_desc(av, ai, bv, bi, keep: int):
+    """Merge two descending (value, index) lists, keep the top ``keep``;
+    ``b`` wins ties (the kernels' truncated UP-k/DN-k merge)."""
+    m, n = av.shape[-1], bv.shape[-1]
+    ra = _iota(m) + (bv[..., None, :] >= av[..., :, None]).sum(-1)
+    rb = _iota(n) + (av[..., :, None] > bv[..., None, :]).sum(-2)
+    rank = jnp.concatenate([ra, rb], axis=-1).astype(jnp.int32)
+    mv, mi = _permute(rank, jnp.concatenate([av, bv], axis=-1),
+                      jnp.concatenate([ai, bi], axis=-1))
+    return mv[..., :keep], mi[..., :keep]
+
+
+def _tree_reduce_desc(vs, is_, k: int):
     """Reduce a (..., S, k) stack of descending lists to (..., k)."""
     neg = sentinel_min(vs.dtype)
     while vs.shape[-2] > 1:
@@ -59,7 +73,7 @@ def _tree_reduce_desc(vs, is_, k: int, use_mxu: bool):
             vs = jnp.pad(vs, pad, constant_values=neg)
             is_ = jnp.pad(is_, pad, constant_values=-1)  # never alias slot 0
         vs, is_ = _merge_desc(vs[..., 0::2, :], is_[..., 0::2, :],
-                              vs[..., 1::2, :], is_[..., 1::2, :], k, use_mxu)
+                              vs[..., 1::2, :], is_[..., 1::2, :], k)
     return vs[..., 0, :], is_[..., 0, :]
 
 
@@ -69,14 +83,12 @@ def local_topk_desc(
     *,
     block: int = 128,
     offset=0,
-    use_mxu: Optional[bool] = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Blockwise descending top-k of (B, E) with global indices ``+offset``.
 
     The in-kernel algorithm of ``router_topk_pallas`` as plain jnp: N-sorter
     per block, then a log-depth tree of truncated LOMS merges. Safe inside
     shard_map/vmap (no pallas_call)."""
-    use_mxu = _resolve_mxu(use_mxu, x.dtype)
     bsz, e = x.shape
     neg = sentinel_min(x.dtype)
     nblk = -(-e // block)
@@ -87,11 +99,9 @@ def local_topk_desc(
     idx = jnp.where(lane < e, lane + jnp.asarray(offset, jnp.int32), -1)
     xb = x.reshape(bsz, nblk, block)
     ib = idx.reshape(bsz, nblk, block)
-    vs, is_ = sort_nsorter(xb, ib, use_mxu=use_mxu)
+    vs, is_ = _sort_desc(xb, ib)
     kk = min(k, block)
-    vs = vs[..., ::-1][..., :kk]
-    is_ = is_[..., ::-1][..., :kk]
-    vs, is_ = _tree_reduce_desc(vs, is_, k, use_mxu)
+    vs, is_ = _tree_reduce_desc(vs[..., :kk], is_[..., :kk], k)
     if vs.shape[-1] < k:  # degenerate: fewer candidates than k on this shard
         pad = [(0, 0)] * (vs.ndim - 1) + [(0, k - vs.shape[-1])]
         vs = jnp.pad(vs, pad, constant_values=neg)
@@ -99,7 +109,7 @@ def local_topk_desc(
     return vs, is_
 
 
-def _butterfly_topk(vals, idxs, k: int, axis: str, size: int, use_mxu: bool):
+def _butterfly_topk(vals, idxs, k: int, axis: str, size: int):
     """XOR-partner butterfly: after log2(size) exchange+merge steps every
     shard holds the identical global top-k."""
     step = 1
@@ -107,7 +117,7 @@ def _butterfly_topk(vals, idxs, k: int, axis: str, size: int, use_mxu: bool):
         perm = [(i, i ^ step) for i in range(size)]
         ov = jax.lax.ppermute(vals, axis, perm)
         oi = jax.lax.ppermute(idxs, axis, perm)
-        vals, idxs = _merge_desc(vals, idxs, ov, oi, k, use_mxu)
+        vals, idxs = _merge_desc(vals, idxs, ov, oi, k)
         step *= 2
     return vals, idxs
 
@@ -119,7 +129,6 @@ def tree_topk(
     mesh: Optional[Mesh] = None,
     axis: Optional[str] = None,
     block: int = 128,
-    use_mxu: Optional[bool] = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Descending top-k (values, int32 indices) over the last axis of (B, E).
 
@@ -127,10 +136,9 @@ def tree_topk(
     sharded over that axis and reduced by the device-tree; otherwise this is
     the single-device log-tree (same merge network, local edges)."""
     assert x.ndim == 2, x.shape
-    use_mxu = _resolve_mxu(use_mxu, x.dtype)
     bsz, e = x.shape
     if mesh is None or axis is None or mesh.shape[axis] == 1:
-        vs, is_ = local_topk_desc(x, k, block=block, use_mxu=use_mxu)
+        vs, is_ = local_topk_desc(x, k, block=block)
         return vs, is_
     size = int(mesh.shape[axis])
     assert e % size == 0, (e, size)
@@ -140,21 +148,20 @@ def tree_topk(
     def body(xs):  # xs: (B, E/size) local shard
         me = jax.lax.axis_index(axis)
         off = (me * shard).astype(jnp.int32)
-        vs, is_ = local_topk_desc(xs, k, block=min(block, shard), offset=off,
-                                  use_mxu=use_mxu)
+        vs, is_ = local_topk_desc(xs, k, block=min(block, shard), offset=off)
         if pow2:
-            return _butterfly_topk(vs, is_, k, axis, size, use_mxu)
+            return _butterfly_topk(vs, is_, k, axis, size)
         allv = jax.lax.all_gather(vs, axis, axis=1)  # (B, S, k)
         alli = jax.lax.all_gather(is_, axis, axis=1)
-        return _tree_reduce_desc(allv, alli, k, use_mxu)
+        return _tree_reduce_desc(allv, alli, k)
 
-    from repro.parallel.sharding import shard_map_compat
 
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         body,
-        mesh,
+        mesh=mesh,
         in_specs=P(None, axis),
         out_specs=(P(None, None), P(None, None)),
+        check_vma=False,
     )
     return fn(x)
 
