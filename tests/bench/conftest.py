@@ -1,0 +1,8 @@
+"""The benchmark's own tests: the harness package lives at the checkout
+root (``bench/``), next to ``src/``."""
+import os
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
