@@ -16,7 +16,8 @@ Two span kinds, matching the two clocks of a JAX program:
 * ``kind="run"`` — host-timed execution regions whose caller has made the
   duration meaningful (``block_until_ready`` before exit, e.g. prefill /
   decode / train-step). Enters ``jax.profiler.TraceAnnotation`` so the
-  region shows up on the host track of an XLA/perfetto profile.
+  region shows up on the host track of an XLA/perfetto profile, its
+  ``attrs`` as the event's stats.
 
 Enablement: ``REPRO_OBS`` env var (any value but ``""``/``"0"``) or
 :func:`set_enabled` (tests / embedding apps). Spans nest through a
@@ -151,8 +152,9 @@ class _LiveSpan:
             # names the ops emitted while this span is open; adds no eqns
             self._jax_ctx = jax.named_scope(self.name)
         else:
-            # host-track annotation in XLA / perfetto profiles
-            self._jax_ctx = jax.profiler.TraceAnnotation(self.name)
+            # host-track annotation in XLA / perfetto profiles; the attrs
+            # become the event's stats
+            self._jax_ctx = jax.profiler.TraceAnnotation(self.name, **self.attrs)
         self._jax_ctx.__enter__()
         self._t0 = time.perf_counter_ns()
         return self

@@ -4,10 +4,28 @@ continuous-batching decode, bit-identical per request to solo generate().
 Disaggregation: prefill batches compile per (bucket length, batch) pair;
 the decode step compiles per batch *composition* — the tuple of per-slot
 (k, greedy, top-p-active) signatures — and is reused for every tick with
-that composition. One scheduler tick = admit everything arrived (FIFO,
-head-of-line blocking), prefill + insert the admissions, then one decode
-step over all occupied slots drawing every request's next token through
-a single segmented ``segment_topk`` launch.
+that composition. The three programs are named ``sched_prefill``,
+``sched_insert`` and ``sched_decode`` (``jit_sched_decode`` on a
+profiler's ``XLA Modules`` line).
+
+One scheduler tick is a row of host phases, each a ``kind="run"`` span
+(so it lands on the profiler's host clock beside the device ops), none
+overlapping another:
+
+  * ``sched.expire``: time out queued and running requests past their
+    deadlines;
+  * ``sched.admit``: admit everything arrived (FIFO, head-of-line
+    blocking);
+  * per prefill batch, ``sched.prefill``: the prefill launch until its
+    logits are ready; then per request ``sched.first_token`` (the eager
+    first draw from its prefill row) and ``sched.insert`` (slot and page
+    allocation, the insert launch);
+  * ``sched.decode_prep``: the decode launch's host arrays and keys;
+  * ``sched.decode``: one decode launch over all occupied slots, drawing
+    every request's next token through a single segmented
+    ``segment_topk``, until the tokens are on the host;
+  * ``sched.decode_commit``: per-request bookkeeping of the new tokens
+    and keys, finished requests released.
 
 Bit-equality oracle (CI-gated, tests/test_scheduler.py): each request's
 token stream equals running it alone through one-shot ``generate()`` with
@@ -178,8 +196,10 @@ class ScheduledEngine:
     def step(self) -> None:
         """One scheduler tick: expire → admit → prefill/insert → one
         decode step."""
-        self._expire()
-        admitted = self._admit()
+        with span("sched.expire", kind="run", n=len(self.queue)):
+            self._expire()
+        with span("sched.admit", kind="run"):
+            admitted = self._admit()
         if admitted:
             self._run_prefill(admitted)
         if self.active:
@@ -330,13 +350,13 @@ class ScheduledEngine:
         if key not in self._prefill_jits:
             cfg = self.cfg
 
-            def f(params, tokens, lengths):
+            def sched_prefill(params, tokens, lengths):
                 cache = init_cache(cfg, bb, blen)
                 logits, cache = prefill(params, {"tokens": tokens}, cache,
                                         cfg, lengths=lengths)
                 return logits, cache["body"]
 
-            self._prefill_jits[key] = jax.jit(f)
+            self._prefill_jits[key] = jax.jit(sched_prefill)
         return self._prefill_jits[key]
 
     def _insert_fn(self, npg: int, blen: int, bb: int):
@@ -344,14 +364,14 @@ class ScheduledEngine:
         if key not in self._insert_jits:
             ps = self.sc.page_size
 
-            def f(leaves, body, row, page_ids):
+            def sched_insert(leaves, body, row, page_ids):
                 out = {}
                 for name, pool_leaf in leaves.items():
                     val = split_pages(body[name], name, row, npg, ps)
                     out[name] = pool_leaf.at[:, page_ids].set(val)
                 return out
 
-            self._insert_jits[key] = jax.jit(f, donate_argnums=(0,))
+            self._insert_jits[key] = jax.jit(sched_insert, donate_argnums=(0,))
         return self._insert_jits[key]
 
     def _first_token(self, logits_row, p: SamplingParams):
@@ -413,19 +433,21 @@ class ScheduledEngine:
         obs_metrics.counter("sched.prefill_batches").inc()
         ps = self.sc.page_size
         for i, r in enumerate(reqs):
-            tok, key = self._first_token(logits[i], r.params)
-            slot, pages = self.slots.alloc(self._npg_need(r))
-            npg_store = math.ceil(r.prompt.size / ps)
-            insert = self._insert_fn(npg_store, blen, bb)
-            try:
-                self.pool.leaves = self._with_retry(
-                    "insert", lambda: insert(
-                        self.pool.leaves, body, jnp.int32(i),
-                        jnp.asarray(pages[:npg_store])))
-            except Exception as e:  # noqa: BLE001 — retries exhausted
-                self.slots.release(slot)  # not yet r.slot: reclaim directly
-                self._fail(r, f"insert failed: {type(e).__name__}: {e}")
-                continue
+            with span("sched.first_token", kind="run", rid=r.rid, k=r.params.k):
+                tok, key = self._first_token(logits[i], r.params)
+            with span("sched.insert", kind="run", rid=r.rid):
+                slot, pages = self.slots.alloc(self._npg_need(r))
+                npg_store = math.ceil(r.prompt.size / ps)
+                insert = self._insert_fn(npg_store, blen, bb)
+                try:
+                    self.pool.leaves = self._with_retry(
+                        "insert", lambda: insert(
+                            self.pool.leaves, body, jnp.int32(i),
+                            jnp.asarray(pages[:npg_store])))
+                except Exception as e:  # noqa: BLE001 — retries exhausted
+                    self.slots.release(slot)  # not yet r.slot: reclaim directly
+                    self._fail(r, f"insert failed: {type(e).__name__}: {e}")
+                    continue
             r.state = RequestState.RUNNING
             r.slot = slot
             r.length = int(r.prompt.size)
@@ -457,54 +479,68 @@ class ScheduledEngine:
         ks = tuple(s[0] for s in sig)
         offsets = tuple(range(0, (ns + 1) * v, v))
 
-        def f(params, leaves, page_table, lengths, tokens, keys, temps, tps):
-            view = gather_view(leaves, page_table, lengths, ps)
-            logits, new_cache = decode_step(params, tokens, {"body": view},
-                                            cfg, positions=lengths[:, None])
+        # the named scopes only label ops (op_name metadata), so a trace's
+        # device ops can be traced back to these five parts of the tick
+        def sched_decode(params, leaves, page_table, lengths, tokens, keys,
+                         temps, tps):
+            with jax.named_scope("gather_view"):
+                view = gather_view(leaves, page_table, lengths, ps)
+            with jax.named_scope("decode_step"):
+                logits, new_cache = decode_step(
+                    params, tokens, {"body": view}, cfg,
+                    positions=lengths[:, None])
             body = new_cache["body"]
-            page_ids = jnp.take_along_axis(
-                page_table, (lengths // ps)[:, None], axis=1)[:, 0]
-            offs = lengths % ps
-            out = {
-                name: scatter_col(leaves[name], name,
-                                  take_col(body[name], name, lengths),
-                                  page_ids, offs)
-                for name in leaves
-            }
-            split = jax.vmap(jax.random.split)(keys)  # (ns, 2, 2)
-            new_keys, subs = split[:, 0], split[:, 1]
-            # one segmented launch scores every slot's vocab row with its
-            # own k; the CSR layout is static (out_offs is a host tuple)
-            vals, _, out_offs = segment_topk(logits.reshape(-1), offsets, ks)
-            toks = []
-            for s, (k_s, greedy, topp) in enumerate(sig):
-                row = logits[s]
-                if greedy:
-                    toks.append(jnp.argmax(row, axis=-1).astype(jnp.int32))
-                    continue
-                vals_s = vals[out_offs[s]:out_offs[s + 1]][None]  # (1, k_s)
-                choice = scored_draw(subs[s], vals_s, temps[s],
-                                     tps[s] if topp else None)
-                toks.append(canonical_token(row[None], vals_s, choice)[0])
-            return out, new_keys, jnp.stack(toks)
+            with jax.named_scope("cache_write"):
+                page_ids = jnp.take_along_axis(
+                    page_table, (lengths // ps)[:, None], axis=1)[:, 0]
+                offs = lengths % ps
+                out = {
+                    name: scatter_col(leaves[name], name,
+                                      take_col(body[name], name, lengths),
+                                      page_ids, offs)
+                    for name in leaves
+                }
+            # the key split stays ahead of the top-k, keeping the op order
+            with jax.named_scope("draw"):
+                split = jax.vmap(jax.random.split)(keys)  # (ns, 2, 2)
+                new_keys, subs = split[:, 0], split[:, 1]
+            with jax.named_scope("segment_topk"):
+                # one segmented launch scores every slot's vocab row with
+                # its own k; the CSR layout is static (out_offs is a host
+                # tuple)
+                vals, _, out_offs = segment_topk(logits.reshape(-1), offsets, ks)
+            with jax.named_scope("draw"):
+                toks = []
+                for s, (k_s, greedy, topp) in enumerate(sig):
+                    row = logits[s]
+                    if greedy:
+                        toks.append(jnp.argmax(row, axis=-1).astype(jnp.int32))
+                        continue
+                    vals_s = vals[out_offs[s]:out_offs[s + 1]][None]  # (1, k_s)
+                    choice = scored_draw(subs[s], vals_s, temps[s],
+                                         tps[s] if topp else None)
+                    toks.append(canonical_token(row[None], vals_s, choice)[0])
+                toks = jnp.stack(toks)
+            return out, new_keys, toks
 
-        self._decode_jits[sig] = jax.jit(f, donate_argnums=(1,))
+        self._decode_jits[sig] = jax.jit(sched_decode, donate_argnums=(1,))
         return self._decode_jits[sig]
 
     def _run_decode(self) -> None:
-        slots = sorted(self.active)
-        reqs = [self.active[s] for s in slots]
-        sig = tuple(r.params.sig for r in reqs)
-        pt = jnp.asarray(self.slots.page_table[slots])
-        lengths = jnp.asarray(np.asarray([r.length for r in reqs], np.int32))
-        tokens = jnp.asarray(
-            np.asarray([[r.tokens[-1]] for r in reqs], np.int32))
-        keys = jnp.stack([r.key for r in reqs])
-        temps = jnp.asarray(
-            np.asarray([r.params.temperature for r in reqs], np.float32))
-        tps = jnp.asarray(
-            np.asarray([r.params.top_p for r in reqs], np.float32))
-        compiled = sig not in self._decode_seen
+        with span("sched.decode_prep", kind="run", batch=len(self.active)):
+            slots = sorted(self.active)
+            reqs = [self.active[s] for s in slots]
+            sig = tuple(r.params.sig for r in reqs)
+            pt = jnp.asarray(self.slots.page_table[slots])
+            lengths = jnp.asarray(np.asarray([r.length for r in reqs], np.int32))
+            tokens = jnp.asarray(
+                np.asarray([[r.tokens[-1]] for r in reqs], np.int32))
+            keys = jnp.stack([r.key for r in reqs])
+            temps = jnp.asarray(
+                np.asarray([r.params.temperature for r in reqs], np.float32))
+            tps = jnp.asarray(
+                np.asarray([r.params.top_p for r in reqs], np.float32))
+            compiled = sig not in self._decode_seen
         t_d0 = time.perf_counter_ns()
         with span("sched.decode", kind="run", batch=len(slots)):
             try:
@@ -518,24 +554,25 @@ class ScheduledEngine:
                 return
             toks = np.asarray(toks)
         t_d1 = time.perf_counter_ns()
-        self._decode_seen.add(sig)
-        if obs_enabled():
-            # one tick span per request sharing the launch window; the
-            # first tick per batch signature pays the jit compile and is
-            # tagged so percentile readers can exclude it (DESIGN.md §17)
+        with span("sched.decode_commit", kind="run", batch=len(slots)):
+            self._decode_seen.add(sig)
+            if obs_enabled():
+                # one tick span per request sharing the launch window; the
+                # first tick per batch signature pays the jit compile and is
+                # tagged so percentile readers can exclude it (DESIGN.md §17)
+                for i, r in enumerate(reqs):
+                    record_span("req.decode", t_d0, t_d1 - t_d0, rid=r.rid,
+                                trace_id=r.trace_id, tick=self.t, slot=slots[i],
+                                batch=len(slots), compiled=compiled)
+            self.pool.leaves = leaves
+            obs_metrics.counter("sched.decode_steps").inc()
+            obs_metrics.counter("sched.tokens").inc(len(slots))
             for i, r in enumerate(reqs):
-                record_span("req.decode", t_d0, t_d1 - t_d0, rid=r.rid,
-                            trace_id=r.trace_id, tick=self.t, slot=slots[i],
-                            batch=len(slots), compiled=compiled)
-        self.pool.leaves = leaves
-        obs_metrics.counter("sched.decode_steps").inc()
-        obs_metrics.counter("sched.tokens").inc(len(slots))
-        for i, r in enumerate(reqs):
-            r.key = new_keys[i]
-            r.length += 1
-            r.tokens.append(int(toks[i]))
-            if len(r.tokens) >= r.params.max_new_tokens:
-                self._finish(r)
+                r.key = new_keys[i]
+                r.length += 1
+                r.tokens.append(int(toks[i]))
+                if len(r.tokens) >= r.params.max_new_tokens:
+                    self._finish(r)
 
     # ------------------------------------------------------------- cleanup
 
