@@ -3,11 +3,13 @@
 The execution pipeline for one segmented call (DESIGN.md §12):
 
 1. ``bucketing`` groups the static segments into pow2 size classes.
-2. Per class, a numpy gather map packs the member segments into a dense
-   ``(n_segments, width)`` tile (invalid lanes point at one shared pad
-   slot), and **one** Pallas launch (`kernels.segmented`) sorts/merges
-   every row — key encode, descending flip, validity compaction and the
-   raw-value/payload gather all inside the kernel.
+2. Per class, the member segments form a dense ``(n_segments, width)``
+   tile: static slices of the flat input where every row is full (the
+   spill classes, vocab rows), else a numpy gather map (invalid lanes
+   point at one shared pad slot). **One** Pallas launch
+   (`kernels.segmented`) sorts/merges every row — key encode, descending
+   flip, validity compaction and the raw-value/payload gather all inside
+   the kernel.
 3. A numpy scatter map writes each row's valid prefix back to the flat
    CSR output; invalid lanes route to a trash slot that is sliced away.
 
@@ -36,6 +38,7 @@ from typing import List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from repro.kernels.common import (
     encode_key_values,
@@ -101,15 +104,17 @@ def _class_plan(widths: Tuple[int, ...], n_segs: int, dtype):
     return plan_op("segmented", widths, batch=n_segs, dtype=dtype)
 
 
-def _record_bucketing(op: str, classes, spill) -> None:
+def _record_bucketing(op: str, classes, spill, offs) -> None:
     """Bucketing telemetry for one segmented call (trace-time: these fire
     once per compilation, the deterministic count). ``classes``/``spill``
     hold :class:`SizeClass` entries for sort/topk and ``(ca, cb)`` pairs
-    for merge.
+    for merge, whose ``offs`` is the pair ``(offs_a, offs_b)``.
 
     The padded-slot waste fraction is the segmented analog of the paper's
     resource column: the share of class-kernel lanes that carry sentinel
-    padding rather than data."""
+    padding rather than data. ``rows_sliced`` / ``rows_gathered`` count
+    the value-tile rows :func:`_class_rows` slices or gathers (a merge
+    pair counts its a and b tiles)."""
     if not obs_trace.enabled():
         return
 
@@ -134,6 +139,13 @@ def _record_bucketing(op: str, classes, spill) -> None:
     obs_metrics.counter("segmented.padded_slots").inc(
         class_slots - class_valid, op=op)
     obs_metrics.counter("segmented.valid_slots").inc(class_valid, op=op)
+    rows = {"rows_sliced": 0, "rows_gathered": 0}
+    for g in (*classes, *spill):
+        for o, c in (zip(offs, g) if isinstance(g, tuple) else [(offs, g)]):
+            way = "rows_gathered" if _row_runs(o, c) is None else "rows_sliced"
+            rows[way] += c.n
+    for way, count in rows.items():
+        obs_metrics.counter(f"segmented.{way}").inc(count, op=op)
     if class_slots:
         obs_metrics.histogram("segmented.padded_waste_frac").observe(
             (class_slots - class_valid) / class_slots, op=op)
@@ -163,6 +175,38 @@ def _ext(x: jnp.ndarray) -> jnp.ndarray:
 
 def _take(ext: jnp.ndarray, gmap: np.ndarray) -> jnp.ndarray:
     return ext[jnp.asarray(gmap)]
+
+
+def _row_runs(offs, cls: SizeClass) -> Optional[List[Tuple[int, int]]]:
+    """``[(start, rows), ...]``: the class's rows as maximal runs that lie
+    back to back in the flat input, or None when any row is padded."""
+    w = cls.width
+    if any(ln != w for ln in cls.lens):
+        return None
+    runs: List[Tuple[int, int]] = []
+    for sid in cls.seg_ids:
+        o = int(offs[sid])
+        if runs and runs[-1][0] + runs[-1][1] * w == o:
+            runs[-1] = (runs[-1][0], runs[-1][1] + 1)
+        else:
+            runs.append((o, 1))
+    return runs
+
+
+def _class_rows(x: jnp.ndarray, offs, cls: SizeClass, n: int) -> jnp.ndarray:
+    """The class's dense ``(cls.n, width[, F])`` tile of flat ``x``.
+
+    Unpadded rows are static slices of ``x``, one per run of back-to-back
+    rows, so full rows laid out contiguously (every vocab sampling call)
+    are a reshape, not an element gather. A padded class gathers from
+    ``x`` plus its pad slot through :func:`gather_map`."""
+    runs = _row_runs(offs, cls)
+    if runs is None:
+        return _take(_ext(x), gather_map(offs, cls, n))
+    w, trail = cls.width, x.shape[1:]
+    parts = [lax.slice_in_dim(x, o, o + r * w).reshape((r, w) + trail)
+             for o, r in runs]
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, 0)
 
 
 def _scatter(out: jnp.ndarray, smap: np.ndarray, dense: jnp.ndarray):
@@ -303,21 +347,19 @@ def segment_sort_impl(
     lengths = segment_lengths(offs)
     mw = max_class_width(values.dtype)
     classes, spill = bucket_segments(lengths, mw)
-    _record_bucketing("segment_sort", classes, spill)
+    _record_bucketing("segment_sort", classes, spill, offs)
     encode = nan_policy == "last" and key_transformable(values.dtype)
-    vext = _ext(values)
-    lext = [_ext(l) for l in lanes]
     out_v = jnp.zeros((n + 1,), values.dtype)
     out_p = jnp.zeros((n + 1,), jnp.int32) if need_perm else None
     out_l = [jnp.zeros((n + 1,) + l.shape[1:], l.dtype) for l in lanes]
 
     for cls in classes:
-        gmap = gather_map(offs, cls, n)
-        dense = _take(vext, gmap)
-        p_dense = [_take(lx, gmap) for lx in lext]
+        dense = _class_rows(values, offs, cls, n)
+        p_dense = [_class_rows(l, offs, cls, n) for l in lanes]
         if cls.width == 1:
             # singleton class: nothing to sort, no network, no launch
-            res_v, res_perm, res_l = dense, jnp.zeros_like(gmap), p_dense
+            res_v, res_l = dense, p_dense
+            res_perm = jnp.zeros((cls.n, 1), jnp.int32)
         else:
             plan = _class_plan((cls.width,), cls.n, values.dtype)
             res_v, res_perm, res_l = segment_class_sort_pallas(
@@ -334,16 +376,16 @@ def segment_sort_impl(
         out_l = [_scatter(o, smap, r) for o, r in zip(out_l, res_l)]
 
     for cls in spill:  # equal exact-length groups past the class budget
-        gmap = gather_map(offs, cls, n)
-        dense = _take(vext, gmap)
+        dense = _class_rows(values, offs, cls, n)
         smap = scatter_map(offs, cls, cls.width)
         if need_perm:
             res_v, res_perm = _spill_sort_perm(
                 dense, descending=descending, nan_policy=nan_policy)
             out_p = _scatter(out_p, smap, res_perm)
-            for o_i, lx in enumerate(lext):
+            for o_i, l in enumerate(lanes):
                 out_l[o_i] = _scatter(
-                    out_l[o_i], smap, _take_perm(_take(lx, gmap), res_perm))
+                    out_l[o_i], smap,
+                    _take_perm(_class_rows(l, offs, cls, n), res_perm))
         else:
             res_v = _spill_sort_values(
                 dense, descending=descending, nan_policy=nan_policy,
@@ -407,9 +449,8 @@ def segment_merge_impl(
     lens_b = segment_lengths(offs_b)
     mw = max_class_width(a.dtype)
     classes, spill = bucket_merge_pairs(lens_a, lens_b, mw)
-    _record_bucketing("segment_merge", classes, spill)
+    _record_bucketing("segment_merge", classes, spill, (offs_a, offs_b))
     encode = nan_policy == "last" and key_transformable(a.dtype)
-    aext, bext = _ext(a), _ext(b)
     lext = [_ext(l) for l in lanes]
     out_v = jnp.zeros((total + 1,), a.dtype)
     out_p = jnp.zeros((total + 1,), jnp.int32) if need_perm else None
@@ -428,8 +469,8 @@ def segment_merge_impl(
         return np.concatenate([ga, gb], axis=1)
 
     for ca, cb in classes:
-        dense_a = _take(aext, gather_map(offs_a, ca, na))
-        dense_b = _take(bext, gather_map(offs_b, cb, nb))
+        dense_a = _class_rows(a, offs_a, ca, na)
+        dense_b = _class_rows(b, offs_b, cb, nb)
         p_dense = [_take(lx, lane_gmap(ca, cb)) for lx in lext]
         plan = _class_plan((ca.width, cb.width), ca.n, a.dtype)
         res_v, res_perm, res_l = segment_class_merge_pallas(
@@ -450,8 +491,8 @@ def segment_merge_impl(
         out_l = [_scatter(o, smap, r) for o, r in zip(out_l, res_l)]
 
     for ca, cb in spill:  # exact-length groups past the class budget
-        dense_a = _take(aext, gather_map(offs_a, ca, na))
-        dense_b = _take(bext, gather_map(offs_b, cb, nb))
+        dense_a = _class_rows(a, offs_a, ca, na)
+        dense_b = _class_rows(b, offs_b, cb, nb)
         ln = ca.width + cb.width
         out_cls = SizeClass(width=ln, seg_ids=ca.seg_ids,
                             lens=(ln,) * ca.n)
@@ -559,10 +600,8 @@ def segment_topk_impl(
 
     mw = max_class_width(values.dtype)
     classes, spill = bucket_segments(lengths, mw)
-    _record_bucketing("segment_topk", classes, spill)
+    _record_bucketing("segment_topk", classes, spill, offs)
     encode = nan_policy == "last" and key_transformable(values.dtype)
-    vext = _ext(values)
-    lext = [_ext(l) for l in lanes]
     out_v = jnp.zeros((total + 1,), values.dtype)
     out_i = jnp.zeros((total + 1,), jnp.int32)
     out_l = [jnp.zeros((total + 1,) + l.shape[1:], l.dtype) for l in lanes]
@@ -573,9 +612,8 @@ def segment_topk_impl(
     for cls in classes:
         cnts = cls_counts(cls)
         k_out = max(max(cnts), 1)
-        gmap = gather_map(offs, cls, n)
-        dense = _take(vext, gmap)
-        p_dense = [_take(lx, gmap) for lx in lext]
+        dense = _class_rows(values, offs, cls, n)
+        p_dense = [_class_rows(l, offs, cls, n) for l in lanes]
         if cls.width == 1:
             res_v = dense[:, :1]
             res_perm = jnp.zeros((cls.n, 1), jnp.int32)
@@ -600,8 +638,7 @@ def segment_topk_impl(
         failpoint("segmented.spill.topk")
         cnts = cls_counts(cls)
         k_out = max(max(cnts), 1)
-        gmap = gather_map(offs, cls, n)
-        dense = _take(vext, gmap)
+        dense = _class_rows(values, offs, cls, n)
         if descending:
             from repro.api.ops import topk as unified_topk
 
@@ -622,9 +659,10 @@ def segment_topk_impl(
         smap = scatter_map(out_offs, cls, k_out, counts=cnts, trash=total)
         out_v = _scatter(out_v, smap, res_v)
         out_i = _scatter(out_i, smap, res_perm)
-        for o_i, lx in enumerate(lext):
-            out_l[o_i] = _scatter(out_l[o_i], smap,
-                                  _take_perm(_take(lx, gmap), res_perm))
+        for o_i, l in enumerate(lanes):
+            out_l[o_i] = _scatter(
+                out_l[o_i], smap,
+                _take_perm(_class_rows(l, offs, cls, n), res_perm))
 
     ptree = None if payload is None else rebuild([o[:total] for o in out_l],
                                                  total)

@@ -413,6 +413,75 @@ def test_plan_routes_segmented_specs():
 
 
 # ---------------------------------------------------------------------------
+# class tiles: full rows are sliced, padded rows gathered
+# ---------------------------------------------------------------------------
+
+
+def _csr(lengths):
+    return tuple(np.concatenate([[0], np.cumsum(lengths)]).astype(int)
+                 .tolist())
+
+
+#: (lengths, ks): adjacent unpadded spill rows; unpadded spill rows with
+#: short segments between them; a padded pow2 class (width 128)
+TILE_CASES = {
+    "adjacent_spill": ((1500,) * 4, (5, 1, 64, 20)),
+    "spaced_spill": ((1500, 7, 1500, 3, 1500), (8, 2, 50, 3, 1)),
+    "padded_class": ((100, 77, 120), (4, 9, 1)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(TILE_CASES))
+def test_class_tiles_bit_identical_to_reference(case):
+    from repro.segmented import segment_sort_impl, segment_topk_impl
+
+    lengths, ks = TILE_CASES[case]
+    offs = _csr(lengths)
+    n = offs[-1]
+    x = jnp.asarray(RNG.permutation(n).astype(np.float32))  # unique keys
+    pay = jnp.asarray(RNG.normal(size=(n, 2)).astype(np.float32))
+    for impl, kw in ((segment_topk_impl, {"k": ks}),
+                     (segment_sort_impl, {"want_perm": True})):
+        got = impl(x, offs, payload=pay, use_kernel=True, interpret=True,
+                   **kw)
+        ref = impl(x, offs, payload=pay, use_kernel=False, **kw)
+        for g, r in zip(got, ref):
+            if isinstance(r, tuple):  # topk's out_offsets
+                assert g == r
+            else:
+                np.testing.assert_array_equal(np.asarray(g), np.asarray(r))
+
+
+def test_full_rows_lower_without_tile_gather():
+    import re
+
+    import repro.obs as obs
+    from repro.obs import metrics
+    from repro.segmented import segment_topk_impl
+
+    rows, v = 4, 2000
+    offs = tuple(range(0, (rows + 1) * v, v))
+    x = jnp.asarray(RNG.normal(size=(rows * v,)).astype(np.float32))
+    prev = obs.set_enabled(True)
+    metrics.reset()
+    try:
+        hlo = jax.jit(lambda t: segment_topk_impl(
+            t, offs, (1, 8, 50, 3), use_kernel=True)[:2]).lower(x).as_text()
+        sliced = metrics.counter("segmented.rows_sliced").value(
+            op="segment_topk")
+        gathered = metrics.counter("segmented.rows_gathered").value(
+            op="segment_topk")
+    finally:
+        metrics.reset()
+        obs.set_enabled(prev)
+    tile_gathers = [ln for ln in hlo.splitlines()
+                    if "gather" in ln
+                    and re.search(rf"-> tensor<{rows}x{v}x", ln)]
+    assert not tile_gathers, tile_gathers
+    assert (sliced, gathered) == (rows, 0)
+
+
+# ---------------------------------------------------------------------------
 # hypothesis ragged sweeps
 # ---------------------------------------------------------------------------
 
