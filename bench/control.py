@@ -6,8 +6,10 @@
 For every seed it drives the cell's own timed path at the cell's own load
 for a short window and prints, as one JSON line per seed, the numbers the
 benchmark compares (the program's reading) beside the control's reading:
-the plain reference computed one precision below the configuration's
-(float8 e4m3 for bf16, ``bench/reference.py``) put in the program's place.
+the plain reference that the configuration file names (``"reference"``,
+``bench/reference.py`` without the key; ``bench.drivers.serve.reference``),
+computed one precision below the configuration's (float8 e4m3 for bf16),
+put in the program's place.
 Both go through the harness's own ``Check`` at the committed limit
 (``program_ok``, ``control_ok``): the control has to come out not ok.
 
@@ -92,9 +94,9 @@ def serve_readings(srv, ctx: Ctx) -> dict:
     rec = srv.drive(ctx)
     e2e = srv.end_to_end(rec)
     checked = srv.checked_requests(srv.pick_checked(rec, ctx.seed))
-    model = ctx.config["model"]
-    prog = serve.served_gaps(srv.params, model, srv.mix, checked)
-    ctrl = serve.served_gaps(srv.params, model, srv.mix, checked, "fp8", control_seed=ctx.seed)
+    prog = serve.served_gaps(srv.params, ctx.config, srv.mix, checked)
+    ctrl = serve.served_gaps(srv.params, ctx.config, srv.mix, checked, "fp8",
+                             control_seed=ctx.seed)
     limit = srv.mix["check"]["served_token_gap"]
     return {"served_token_gap": max(prog), "per_request": prog,
             "program_ok": Check("served_token_gap", max(prog), limit).ok,

@@ -28,17 +28,21 @@ collection in the window does not walk it.
 
 Correctness: after the window, a sample of finished requests drawn from
 the seed, with the one that served the most tokens, is run through the
-plain float32 reference (``bench/reference.py``) over its prompt and served
-tokens. A top-k draw is one of its row's k best, so no served token's
-reference logit may lie below the reference's k-th best by more than the
-limit: the widest such gap is compared. A program obtained (compiled or
-loaded) inside the window, which a fallback would need, and a launch run
-again after a failure both make the run not correct.
+configuration's plain float32 reference over its prompt and served tokens:
+``bench/<module>.py`` for the configuration file's ``"reference":
+"<module>"``, ``bench/reference.py`` without the key (``reference``). A
+top-k draw is one of its row's k best, so no served token's reference
+logit may lie below the reference's k-th best by more than the limit: the
+widest such gap is compared. A program obtained (compiled or loaded)
+inside the window, which a fallback would need, and a launch run again
+after a failure both make the run not correct.
 """
 from __future__ import annotations
 
 import gc
+import importlib
 import math
+import re
 from typing import Dict, List
 
 import numpy as np
@@ -50,9 +54,27 @@ from bench.harness import (Check, CompileCounter, Ctx, GcWatch, Outcome, TraceSl
 
 
 def model_config(entry: dict):
-    from repro.configs.base import ModelConfig
+    """The program's ``ModelConfig`` of a configuration file's model block;
+    nested ``mla`` and ``moe`` groups become the program's own dataclasses
+    (a JSON list a tuple, so the config stays hashable)."""
+    from repro.configs.base import MLAConfig, ModelConfig, MoEConfig
 
-    return ModelConfig(**entry["model"])
+    m = dict(entry["model"])
+    for key, group in (("mla", MLAConfig), ("moe", MoEConfig)):
+        if isinstance(m.get(key), dict):
+            m[key] = group(**{k: tuple(v) if isinstance(v, list) else v
+                              for k, v in m[key].items()})
+    return ModelConfig(**m)
+
+
+def reference(entry: dict):
+    """The configuration's plain reference module, ``bench/<name>.py`` for its
+    ``"reference"`` key (``reference`` without it). A reference module keeps
+    the contract written in ``bench/reference.py``'s docstring."""
+    name = entry.get("reference", "reference")
+    if not re.fullmatch(r"[A-Za-z_][A-Za-z0-9_]*", name):
+        raise ValueError(f"reference {name!r} names no module under bench/")
+    return importlib.import_module(f"bench.{name}")
 
 
 def sched_config(entry: dict, mix: dict):
@@ -403,10 +425,11 @@ def _sleep_until(t_ns: int) -> None:
         time.sleep(dt)
 
 
-def served_gaps(params, model: dict, mix: dict, checked: list, precision: str = "f32",
+def served_gaps(params, entry: dict, mix: dict, checked: list, precision: str = "f32",
                 control_seed=None) -> List[float]:
     """Per request, the widest gap by which a served token's reference logit
-    lies below the reference's k-th best at its position.
+    lies below the reference's k-th best at its position; the reference is
+    the one the configuration file ``entry`` names (``reference``).
 
     With ``control_seed`` the served tokens are replaced by the tokens the
     reference at ``precision`` would draw itself (top-k at the mix's
@@ -415,9 +438,9 @@ def served_gaps(params, model: dict, mix: dict, checked: list, precision: str = 
     import jax
     import jax.numpy as jnp
 
-    from bench import reference
     from bench.weights import seed_key
 
+    ref_mod, model = reference(entry), entry["model"]
     s_pad = mix["prompt"]["max"] + mix["output"]["max"]
     n_pad = mix["output"]["max"]
     temp = mix["sampling"]["temperature"]
@@ -429,12 +452,12 @@ def served_gaps(params, model: dict, mix: dict, checked: list, precision: str = 
         seq[prompt.size:prompt.size + n - 1] = toks[:-1]
         rows = np.full(n_pad, prompt.size - 1, np.int32)
         rows[:n] = prompt.size - 1 + np.arange(n)
-        ref = reference.logits_at(params, model, seq, rows, "f32")[:n]
+        ref = ref_mod.logits_at(params, model, seq, rows, "f32")[:n]
         kth = jax.lax.top_k(ref, k)[0][:, k - 1]
         if control_seed is None:
             drawn = jnp.asarray(toks)
         else:
-            low = reference.logits_at(params, model, seq, rows, precision)[:n]
+            low = ref_mod.logits_at(params, model, seq, rows, precision)[:n]
             vals, idx = jax.lax.top_k(low, k)
             keys = jax.random.split(seed_key(control_seed, salt=100 + j), n)
             choice = jax.vmap(lambda kk, v: jax.random.categorical(kk, v / temp))(keys, vals)
@@ -494,7 +517,7 @@ def run(ctx: Ctx) -> Outcome:
     checked_ids = srv.pick_checked(rec, ctx.seed)
     checked = srv.checked_requests(checked_ids)
     srv.free_engine()
-    gaps = served_gaps(srv.params, ctx.config["model"], mix, checked)
+    gaps = served_gaps(srv.params, ctx.config, mix, checked)
     n_tok = sum(len(t) for _, t, _ in checked)
     log(f"[serve] reference over {len(checked)} requests, {n_tok} served tokens: "
         f"widest gap per request {[round(g, 5) for g in gaps]}")

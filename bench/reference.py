@@ -1,5 +1,13 @@
 """Plain float32 reference forward pass for the dense decoder family.
 
+The contract every reference module under ``bench/`` keeps (a configuration
+file names its module with ``"reference": "<module>"``, this one without
+the key): ``logits_at(params, model, tokens, rows, precision)`` returns the
+logits ``(len(rows), vocab)`` at positions ``rows`` of the one sequence
+``tokens``, from the weight tree ``bench/weights.py`` drew and the file's
+model block ``model``; ``precision`` is ``"f32"`` (float32 under
+``jax.default_matmul_precision("highest")``) or ``"fp8"`` (the control).
+
 Straight ``jax.numpy`` in float32 at ``Precision.HIGHEST``: no kernels, no
 cache, no batching, causal softmax attention written out in full. It
 imports nothing of the program; it reads the weight tree the benchmark drew

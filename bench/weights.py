@@ -6,8 +6,10 @@ leaf is drawn here, in one jitted call, directly in the serving dtype.
 The plain reference reads the same tree by leaf name.
 
 Scales: projections N(0, 1/fan_in) so activations stay O(1) through the
-depth; embeddings N(0, 1); norm scales 1 + N(0, 0.1) and biases N(0, 0.1),
-so that neither is the identity the reference could silently skip.
+depth, the fan-in read from the logical axis names ``model_init`` declares
+for each leaf (``fan_in``), so that any family's tree is drawn alike;
+embeddings N(0, 1); norm scales 1 + N(0, 0.1) and biases N(0, 0.1), so
+that neither is the identity the reference could silently skip.
 """
 from __future__ import annotations
 
@@ -24,37 +26,61 @@ def seed_key(seed: int, salt: int = 0) -> jax.Array:
     return jnp.asarray(words, jnp.uint32)
 
 
-def _leaf_rule(path: tuple, shape: tuple):
+def fan_in(shape: tuple, axes: tuple) -> int:
+    """A leaf's input width, from the logical axis names the program declares
+    for it: a stacked leaf has one leading axis more than its names (the
+    layer axis), and an ``expert`` axis only indexes copies; the first axis
+    left is the one each output sums over."""
+    own = zip(shape[len(shape) - len(axes):], axes)
+    return next(n for n, a in own if a != "expert")
+
+
+def _leaf_rule(path: tuple, shape: tuple, axes: tuple):
     """(kind, std) for one leaf: kind is 'normal', 'norm' or 'bias'."""
     name = path[-1]
-    stacked = path[0] == "stack"
     if name == "scale":
         return "norm", 0.1
     if name == "b":
         return "bias", 0.1
     if name == "emb":
         return "normal", 1.0
-    fan_in = shape[1] if stacked else shape[0]
-    return "normal", 1.0 / math.sqrt(fan_in)
+    return "normal", 1.0 / math.sqrt(fan_in(shape, axes))
 
 
 def _path_names(path) -> tuple:
     return tuple(getattr(p, "key", getattr(p, "idx", None)) for p in path)
 
 
-def make_weights(cfg, seed: int):
-    """The parameter tree of ``cfg`` in ``cfg.dtype``, drawn from ``seed``."""
+def leaf_specs(cfg):
+    """The parameter tree of ``cfg`` as the program's ``model_init`` declares
+    it, with nothing computed: its treedef and, leaf by leaf in draw order,
+    ``(path, shape, kind, std)``."""
     from repro.models import model_init
 
-    shapes = jax.eval_shape(lambda k: model_init(k, cfg)[0], jax.random.PRNGKey(0))
+    declared = {}
+
+    def init(key):
+        params, declared["axes"] = model_init(key, cfg)
+        return params
+
+    shapes = jax.eval_shape(init, jax.random.PRNGKey(0))
     flat, treedef = jax.tree_util.tree_flatten_with_path(shapes)
-    specs = [(_path_names(p), tuple(s.shape)) for p, s in flat]
+    axes = treedef.flatten_up_to(declared["axes"])
+    specs = []
+    for (p, s), a in zip(flat, axes):
+        path, shape = _path_names(p), tuple(s.shape)
+        specs.append((path, shape) + _leaf_rule(path, shape, tuple(a)))
+    return treedef, specs
+
+
+def make_weights(cfg, seed: int):
+    """The parameter tree of ``cfg`` in ``cfg.dtype``, drawn from ``seed``."""
+    treedef, specs = leaf_specs(cfg)
     dtype = jnp.dtype(cfg.dtype)
 
     def draw(key):
         out = []
-        for i, (path, shape) in enumerate(specs):
-            kind, std = _leaf_rule(path, shape)
+        for i, (_, shape, kind, std) in enumerate(specs):
             z = jax.random.normal(jax.random.fold_in(key, i), shape, jnp.float32) * std
             if kind == "norm":
                 z = z + 1.0

@@ -14,25 +14,29 @@ import pytest
 from bench import control, run as bench_run
 from bench.drivers import sampler, serve
 from bench.harness import Check, Ctx
-from cells import shrink
+from cells import cells_of, shrink
 
 
 def test_sampler_control_fails_where_the_program_is_exact():
-    spec = bench_run.resolve(bench_run.load_benchmark(), "qwen3-8b.sample")
-    limit = spec["traffic"]["check"]["topk_rows_differing"]
     ks = (1, 8, 20, 40, 50, 64, 50, 40)
     x = (jax.random.normal(jax.random.PRNGKey(3), (8, 4096)) * 2).astype(jnp.bfloat16)
     host = np.asarray(x.astype(jnp.float32))
-    assert not Check("topk_rows_differing", control.fp8_topk_rows_differing(host, ks), limit).ok
     exact = np.stack([np.sort(r)[::-1] for r in host])
     vals = np.concatenate([exact[s, :k] for s, k in enumerate(ks)])
     toks = np.argmax(host, axis=-1)
     bad_vals, bad_toks = sampler.compare(host, vals, toks, ks, (1.0,) * 8, 0.8)
-    assert Check("topk_rows_differing", bad_vals, limit).ok
-    assert Check("tokens_outside_candidates", bad_toks, limit).ok
+    ctrl = control.fp8_topk_rows_differing(host, ks)
+    cells = cells_of("sampler")
+    assert cells
+    for cell in cells:
+        limit = bench_run.resolve(bench_run.load_benchmark(), cell)["traffic"]["check"][
+            "topk_rows_differing"]
+        assert not Check("topk_rows_differing", ctrl, limit).ok
+        assert Check("topk_rows_differing", bad_vals, limit).ok
+        assert Check("tokens_outside_candidates", bad_toks, limit).ok
 
 
-@pytest.mark.parametrize("cell", ["qwen3-8b.chat", "chatglm3-6b.batch"])
+@pytest.mark.parametrize("cell", cells_of("serve"))
 def test_serve_control_reads_above_the_program(cell):
     """At smoke size the fp8 control's widest gap often stays under the
     committed limit (the limit is read at the cells' own widths, where the

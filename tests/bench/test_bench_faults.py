@@ -7,9 +7,7 @@ import time
 import pytest
 
 from bench.run import run_cell
-from cells import alter_tokens, flaky_decode, shrink
-
-CELLS = ["qwen3-8b.chat", "qwen3-8b.sample", "chatglm3-6b.batch"]
+from cells import CELLS, alter_tokens, cells_of, flaky_decode, shrink
 
 
 def _run(cell, fault=None, trace=False):
@@ -32,7 +30,7 @@ def test_altered_answers_are_not_correct(cell):
     assert not line["correct"], line["checks"]
 
 
-@pytest.mark.parametrize("cell", ["qwen3-8b.chat", "chatglm3-6b.batch"])
+@pytest.mark.parametrize("cell", cells_of("serve"))
 def test_retried_launch_is_not_correct(cell):
     line = _run(cell, fault=flaky_decode)
     assert line["checks"]["launch_retries_in_window"]["value"] > 0
